@@ -35,6 +35,7 @@ from repro.checkpoint import Checkpointer, agent_state, install_agent_state
 from repro.core.agent import AgentConfig, AqoraAgent
 from repro.core.encoding import WorkloadMeta
 from repro.core.train_loop import evaluate, train_agent
+from repro.jax_cache import enable_compile_cache
 from repro.sql import datagen, workloads
 from repro.sql.cbo import Estimator
 
@@ -62,6 +63,7 @@ def main():
                          "and continue training from it")
     args = ap.parse_args()
     logging.basicConfig(level=logging.INFO, format="%(message)s")
+    log.info(f"compile cache: {enable_compile_cache()}")
 
     log.info("building database + workload ...")
     db = datagen.make_job_like(scale=args.scale, seed=0)

@@ -14,6 +14,11 @@ import jax.numpy as jnp
 
 from repro.models.common import normal_init, split_keys
 
+# The TPU's default f32 matmul is one bf16 pass: its logits differ from the
+# host's by ~1e-2, enough to flip a greedy action or a gate verdict. Every
+# policy matmul, and the fused kernel's, runs at full f32 on every backend.
+MATMUL_PRECISION = "highest"
+
 
 # ------------------------------------------------------------------ treecnn
 def _init_treeconv(key, d_in, d_out):
@@ -165,9 +170,9 @@ def apply_encoder(params, kind, feat, left, right, mask, *, fused=False,
             from repro.kernels.tree_conv import tree_cnn_fused
             return tree_cnn_fused(feat, left, right, mask, params,
                                   interpret=interpret)
-        return jax.vmap(fn, in_axes=(None, 0, 0, 0, 0))(
-            params, feat, left, right, mask)
-    return fn(params, feat, left, right, mask)
+        fn = jax.vmap(fn, in_axes=(None, 0, 0, 0, 0))
+    with jax.default_matmul_precision(MATMUL_PRECISION):
+        return fn(params, feat, left, right, mask)
 
 
 def init_mlp_head(key, d_in, d_hidden, d_out):
@@ -179,5 +184,6 @@ def init_mlp_head(key, d_in, d_hidden, d_out):
 
 
 def apply_mlp_head(p, x):
-    h = jax.nn.leaky_relu(x @ p["w1"] + p["b1"])
-    return h @ p["w2"] + p["b2"]
+    with jax.default_matmul_precision(MATMUL_PRECISION):
+        h = jax.nn.leaky_relu(x @ p["w1"] + p["b1"])
+        return h @ p["w2"] + p["b2"]

@@ -32,6 +32,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from repro.core.nets import MATMUL_PRECISION
+
 
 def _kernel(h_ref, lo_ref, ro_ref, m_ref, wr_ref, wl_ref, wrt_ref, b_ref,
             o_ref):
@@ -122,7 +124,8 @@ def _fused_kernel(h_ref, li_ref, ri_ref, m_ref,
         o_ref[t] = pooled.astype(o_ref.dtype)
         return carry
 
-    jax.lax.fori_loop(0, TB, one_tree, 0)
+    with jax.default_matmul_precision(MATMUL_PRECISION):
+        jax.lax.fori_loop(0, TB, one_tree, 0)
 
 
 def _fused_forward(feat, left, right, mask, params, tile, interpret):
@@ -211,8 +214,9 @@ def _fused_bwd(tile, interpret, residuals, g):
         return jax.vmap(_ref_tree_cnn, in_axes=(0, 0, 0, 0, None))(
             f, left, right, m, p)
 
-    _, pullback = jax.vjp(ref, feat, mask, params)
-    gf, gm, gp = pullback(g)
+    with jax.default_matmul_precision(MATMUL_PRECISION):
+        _, pullback = jax.vjp(ref, feat, mask, params)
+        gf, gm, gp = pullback(g)
     zero_int = lambda x: np.zeros(x.shape, jax.dtypes.float0)
     return gf, zero_int(left), zero_int(right), gm, gp
 
@@ -220,7 +224,6 @@ def _fused_bwd(tile, interpret, residuals, g):
 _fused_with_vjp.defvjp(_fused_fwd, _fused_bwd)
 
 
-@functools.partial(jax.jit, static_argnames=("tile", "interpret"))
 def tree_cnn_fused(feat, left, right, mask, params, *, tile=8,
                    interpret=None):
     """Fused TreeCNN encoder: conv1..conv3 + residual + masked max-pool.
@@ -230,7 +233,9 @@ def tree_cnn_fused(feat, left, right, mask, params, *, tile=8,
     dict {"conv1"|"conv2"|"conv3": {"wr","wl","wrt","b"}}. Returns (B, H)
     pooled encodings. Only (B, N) index vectors cross HBM — the one-hot
     matrices and all intermediate activations exist in VMEM only.
-    `interpret=None` auto-selects interpreter mode off-TPU.
+    `interpret=None` auto-selects interpreter mode off-TPU. It is resolved
+    here, outside the jit, so the choice is part of the compiled program's
+    cache key and a Mosaic trace is never reused for an interpreted call.
 
     Differentiable w.r.t. feat, mask and params via a custom VJP (backward
     rematerializes through the jnp reference), so PPO training can run
@@ -238,4 +243,7 @@ def tree_cnn_fused(feat, left, right, mask, params, *, tile=8,
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    return _fused_with_vjp(feat, left, right, mask, params, tile, interpret)
+    return _fused_jit(feat, left, right, mask, params, tile, interpret)
+
+
+_fused_jit = jax.jit(_fused_with_vjp, static_argnums=(5, 6))

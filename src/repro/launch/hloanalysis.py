@@ -23,15 +23,9 @@ from typing import Dict, List, Optional, Tuple
 
 
 def cost_analysis_dict(compiled) -> Dict[str, float]:
-    """Normalize `compiled.cost_analysis()` across jax versions: older
-    releases returned a one-element list of per-program dicts, newer ones
-    return the dict directly (and may return None for trivial programs).
-    Every caller goes through this seam instead of calling `.get` on
-    whatever shape the installed jax produces."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return dict(cost) if cost else {}
+    """`compiled.cost_analysis()` as a plain dict (empty when XLA reports
+    nothing for a trivial program)."""
+    return dict(compiled.cost_analysis() or {})
 
 _DTYPE_BYTES = {
     "pred": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2,

@@ -4,16 +4,7 @@ touches jax device state (the dry-run must set XLA_FLAGS before first init).
 from __future__ import annotations
 
 import jax
-
-
-def _axis_types_kw(n: int) -> dict:
-    """`jax.sharding.AxisType` was removed from newer jax releases; when
-    absent, `jax.make_mesh` defaults every axis to Auto anyway, so the
-    explicit kwarg is only passed where the enum still exists."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n}
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -24,10 +15,12 @@ def make_production_mesh(*, multi_pod: bool = False):
     pods can join/leave elastically (see runtime/elastic.py)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_types_kw(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh():
     """Whatever devices exist (CPU smoke tests: 1 device)."""
     n = len(jax.devices())
-    return jax.make_mesh((1, n), ("data", "model"), **_axis_types_kw(2))
+    return jax.make_mesh((1, n), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)

@@ -160,10 +160,7 @@ def _dispatch_shard_map(xt, eidx, gate, p, cfg, pol, act):
     the global scatter (measured 2.4 TB/device on dbrx train_4k).
     """
     from jax.sharding import PartitionSpec as P
-    try:                                  # jax >= 0.5 top-level export
-        from jax import shard_map
-    except ImportError:                   # 0.4.x keeps it in experimental
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     m = cfg.moe
     E, K = m.n_experts, m.top_k
