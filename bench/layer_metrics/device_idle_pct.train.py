@@ -1,0 +1,8 @@
+"""Share of the training window in which no operation ran on the device."""
+
+
+def read(record):
+    if record["drive"] != "train":
+        return None
+    tr = record["trace"]
+    return (1.0 - tr["busy_s"] / tr["window_s"]) * 100.0
