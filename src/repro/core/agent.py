@@ -19,6 +19,7 @@ from repro.core import nets
 from repro.core.actions import ActionSpace
 from repro.core.encoding import MAX_NODES, WorkloadMeta
 from repro.optim import AdamWConfig, adamw_init, adamw_update
+from repro.spans import span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -222,17 +223,27 @@ class AqoraAgent:
         nodes, so this is exact, and it cuts the dominant O(N) encoder
         cost without fragmenting the jit cache.
         """
-        if self.cfg.net != "fcnn":       # fcnn flattens all MAX_NODES slots
-            mask = np.asarray(mask)
-            n = min(self._nodes, _node_bucket(int(mask.sum(axis=1).max()) + 1))
-            feat, left, right, mask = (np.asarray(feat)[:, :n],
-                                       np.asarray(left)[:, :n],
-                                       np.asarray(right)[:, :n], mask[:, :n])
-        a, logp, new_keys = self._act_batch_jit(
-            self.actor, jnp.asarray(feat), jnp.asarray(left),
-            jnp.asarray(right), jnp.asarray(mask), jnp.asarray(amask),
-            jnp.asarray(keys), explore=explore)
-        a, logp, new_keys = jax.device_get((a, logp, new_keys))
+        # profile spans: the call as a whole, the host side that feeds the
+        # program (trim, copies to the device, dispatch) and the wait for
+        # its results; the program itself runs as `jit_act_batch_fn`
+        with span("lqrs.policy") as sp:
+            with span("lqrs.policy.feed"):
+                n = np.shape(feat)[1]
+                if self.cfg.net != "fcnn":   # fcnn flattens all MAX_NODES
+                    mask = np.asarray(mask)
+                    n = min(self._nodes,
+                            _node_bucket(int(mask.sum(axis=1).max()) + 1))
+                    feat, left, right, mask = (np.asarray(feat)[:, :n],
+                                               np.asarray(left)[:, :n],
+                                               np.asarray(right)[:, :n],
+                                               mask[:, :n])
+                sp.set_metadata(nodes=n)
+                a, logp, new_keys = self._act_batch_jit(
+                    self.actor, jnp.asarray(feat), jnp.asarray(left),
+                    jnp.asarray(right), jnp.asarray(mask),
+                    jnp.asarray(amask), jnp.asarray(keys), explore=explore)
+            with span("lqrs.policy.fetch"):
+                a, logp, new_keys = jax.device_get((a, logp, new_keys))
         return np.asarray(a), np.asarray(logp), np.asarray(new_keys)
 
     def act_keyed(self, enc_state, amask: np.ndarray, key,

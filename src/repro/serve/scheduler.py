@@ -51,6 +51,7 @@ from repro.core.encoding import MAX_NODES, encode_state
 from repro.core.rollout import Trajectory, as_key, finalize_trajectory
 from repro.serve.cache import PartitionedStageCache
 from repro.serve.deltas import DeltaBatch, apply_delta
+from repro.spans import span
 from repro.sql.cbo import Estimator
 from repro.sql.cluster import ClusterModel
 from repro.sql.executor import AdaptiveRun, RunResult
@@ -274,21 +275,29 @@ class LaneScheduler:
         pending = deque(sorted(stream, key=lambda a: a.t))
         self._pending = pending       # the recovery plane requeues retries
         while True:
-            self._admit(pending)
-            if self.recovery is not None:
-                # speculative execution claims lanes the admission queue
-                # left idle (so hedges never starve real arrivals)
-                self.recovery.maybe_hedge()
-            susp = [l for l in self.lanes if l.state is not None]
-            if not susp:
-                assert not pending, "admission stalled with idle lanes"
-                break
-            t_min = min(l.next_event for l in susp)
-            horizon = np.inf if self.window is None else t_min + self.window
-            self._decide([l for l in susp if l.next_event <= horizon])
-            self.ticks += 1
-            if self.obs is not None:
-                self.obs.on_tick(t_min)
+            # profile span: one pass of the loop. Its self time is the
+            # admission and hedging bookkeeping; admissions, the decision,
+            # each lane's apply/resume and each completion nest inside it
+            with span("lqrs.tick") as sp:
+                self._admit(pending)
+                if self.recovery is not None:
+                    # speculative execution claims lanes the admission
+                    # queue left idle (so hedges never starve arrivals)
+                    self.recovery.maybe_hedge()
+                susp = [l for l in self.lanes if l.state is not None]
+                if not susp:
+                    assert not pending, "admission stalled with idle lanes"
+                    sp.set_metadata(lanes=0)
+                    break
+                t_min = min(l.next_event for l in susp)
+                horizon = np.inf if self.window is None \
+                    else t_min + self.window
+                decide = [l for l in susp if l.next_event <= horizon]
+                sp.set_metadata(lanes=len(decide))
+                self._decide(decide)
+                self.ticks += 1
+                if self.obs is not None:
+                    self.obs.on_tick(t_min)
         return sorted(self.completions, key=lambda c: c.seq)
 
     def schedule_barrier(self, fn: Callable, label: str = "task") -> None:
@@ -419,65 +428,70 @@ class LaneScheduler:
     def _start(self, lane: _Lane, arrival: Arrival, admit_t: float, *,
                hook_budget: Optional[int] = None, degraded: bool = False,
                predicted: Optional[float] = None) -> None:
-        q = arrival.query
-        ticket = arrival.ticket
-        if ticket is not None:
-            # a retry/hedge re-admission: the ticket overrides the hook
-            # budget (0 by default — retries run the resumed/replanned
-            # remainder without competing for policy bandwidth)
-            hook_budget = ticket.hook_budget
-        # plan-memory fast path: probe AHEAD of the agent — on a hit the
-        # run gets exactly len(actions) suspensions and `_replay` scripts
-        # them, so this query never enters an act_batch. Retries keep
-        # their ticket semantics (a memoized plan already failed once on
-        # this band would be fenced by the completion hook anyway).
-        memo = None
-        if arrival.ticket is None and self.plan_memory is not None:
-            memo = self.plan_memory.probe(q, self.db.versions)
-            if self.obs is not None:
-                self.obs.event(
-                    "plan_memory_hit" if memo is not None
-                    else "plan_memory_miss",
-                    {"lane": lane.idx, "query": q.name}, t=admit_t)
-        if memo is not None:
-            steps = len(memo.actions)
-        else:
-            steps = self.agent.cfg.max_steps if hook_budget is None \
-                else min(hook_budget, self.agent.cfg.max_steps)
-        cache = None
-        shared = getattr(self.db, "_stage_cache", None)
-        if self.reuse_stages and isinstance(shared, PartitionedStageCache):
-            cache = shared.partition(arrival.tenant)
-        plan = syntactic_plan(q) if ticket is None or ticket.plan is None \
-            else ticket.plan
-        faults = None
-        if self.recovery is not None:
-            faults = self.recovery.run_faults(arrival)
-            self.recovery.on_admit(arrival, admit_t)
-        # the tracer opens an attempt record and returns the sink the
-        # executor writes scan/join/failure notes into
-        trace = None if self.obs is None \
-            else self.obs.on_admit(lane, arrival, admit_t)
-        run = AdaptiveRun(self.db, q, plan, self.est,
-                          self.cluster, max_hook_steps=steps,
-                          plan_time=0.0, reuse_stages=self.reuse_stages,
-                          cache=cache, faults=faults,
-                          init_mats=None if ticket is None else ticket.mats,
-                          init_stages_done=0 if ticket is None
-                          else ticket.stages_done, trace=trace)
-        lane.run, lane.traj = run, Trajectory()
-        lane.key = as_key(arrival.seed if arrival.seed is not None
-                          else lane.idx)
-        lane.extra_plan = 0.0
-        lane.arrival, lane.admit_t = arrival, admit_t
-        lane.hook_budget, lane.degraded = hook_budget, degraded
-        lane.predicted = predicted
-        lane.memoized = memo is not None
-        lane.state = run.start()
-        if memo is not None and lane.state is not None:
-            self._replay(lane, memo)
-        if lane.state is None:        # ran to completion with no boundary
-            self._finish(lane)
+        # profile span: admission of one query, up to its first stage
+        # boundary (base scans that precede it run here), or to its
+        # completion when the run has no boundary
+        with span("lqrs.admit", seq=arrival.seq):
+            q = arrival.query
+            ticket = arrival.ticket
+            if ticket is not None:
+                # a retry/hedge re-admission: the ticket overrides the hook
+                # budget (0 by default — retries run the resumed/replanned
+                # remainder without competing for policy bandwidth)
+                hook_budget = ticket.hook_budget
+            # plan-memory fast path: probe AHEAD of the agent — on a hit the
+            # run gets exactly len(actions) suspensions and `_replay` scripts
+            # them, so this query never enters an act_batch. Retries keep
+            # their ticket semantics (a memoized plan already failed once on
+            # this band would be fenced by the completion hook anyway).
+            memo = None
+            if arrival.ticket is None and self.plan_memory is not None:
+                memo = self.plan_memory.probe(q, self.db.versions)
+                if self.obs is not None:
+                    self.obs.event(
+                        "plan_memory_hit" if memo is not None
+                        else "plan_memory_miss",
+                        {"lane": lane.idx, "query": q.name}, t=admit_t)
+            if memo is not None:
+                steps = len(memo.actions)
+            else:
+                steps = self.agent.cfg.max_steps if hook_budget is None \
+                    else min(hook_budget, self.agent.cfg.max_steps)
+            cache = None
+            shared = getattr(self.db, "_stage_cache", None)
+            if self.reuse_stages and isinstance(shared, PartitionedStageCache):
+                cache = shared.partition(arrival.tenant)
+            plan = syntactic_plan(q) if ticket is None or ticket.plan is None \
+                else ticket.plan
+            faults = None
+            if self.recovery is not None:
+                faults = self.recovery.run_faults(arrival)
+                self.recovery.on_admit(arrival, admit_t)
+            # the tracer opens an attempt record and returns the sink the
+            # executor writes scan/join/failure notes into
+            trace = None if self.obs is None \
+                else self.obs.on_admit(lane, arrival, admit_t)
+            run = AdaptiveRun(self.db, q, plan, self.est,
+                              self.cluster, max_hook_steps=steps,
+                              plan_time=0.0, reuse_stages=self.reuse_stages,
+                              cache=cache, faults=faults,
+                              init_mats=None if ticket is None
+                              else ticket.mats,
+                              init_stages_done=0 if ticket is None
+                              else ticket.stages_done, trace=trace)
+            lane.run, lane.traj = run, Trajectory()
+            lane.key = as_key(arrival.seed if arrival.seed is not None
+                              else lane.idx)
+            lane.extra_plan = 0.0
+            lane.arrival, lane.admit_t = arrival, admit_t
+            lane.hook_budget, lane.degraded = hook_budget, degraded
+            lane.predicted = predicted
+            lane.memoized = memo is not None
+            lane.state = run.start()
+            if memo is not None and lane.state is not None:
+                self._replay(lane, memo)
+            if lane.state is None:        # ran to completion with no boundary
+                self._finish(lane)
 
     def _replay(self, lane: _Lane, entry) -> None:
         """Script a memoized entry's stored actions through the lane's run
@@ -504,9 +518,17 @@ class LaneScheduler:
                 self.obs.on_decide(lane, lane.next_event,
                                    lane.traj.decoded[-1], r)
             lane.traj.hook_seconds += time.perf_counter() - t0
-            lane.state = lane.run.resume(new_plan)
+            self._resume(lane, new_plan)
         while lane.state is not None:      # entry shorter than boundaries
-            lane.state = lane.run.resume(None)
+            self._resume(lane, None)
+
+    def _resume(self, lane: _Lane, new_plan) -> None:
+        """Deliver a decision to the lane's run and execute to its next
+        stage boundary, or to its end (then `lane.state` is None)."""
+        # profile span: the executor's stages for this lane; no span is
+        # held across the run's suspension, which belongs to no lane
+        with span("lqrs.resume", seq=lane.arrival.seq):
+            lane.state = lane.run.resume(new_plan)
 
     # ------------------------------------------------------------ deciding
     def _decide(self, decide: List[_Lane]) -> None:
@@ -516,88 +538,101 @@ class LaneScheduler:
         agent, meta = self.agent, self.agent.meta
         B, F, d = self.n_lanes, self.agent.meta.feat_dim, self.agent.space.d
         self.decide_sizes.append(len(decide))
-        feat = np.zeros((B, MAX_NODES, F), np.float32)
-        left = np.zeros((B, MAX_NODES), np.int32)
-        right = np.zeros((B, MAX_NODES), np.int32)
-        mask = np.zeros((B, MAX_NODES), np.float32)
-        amask = np.zeros((B, d), np.float32)
-        amask[:, agent.space.noop_idx] = 1.0   # padded slots sample noop
-        keys = np.zeros((B, 2), np.uint32)
-        encs, prep_t = {}, {}
-        for lane in decide:
-            bi = lane.idx
-            t0 = time.perf_counter()
-            enc = encode_state(lane.state, meta)
-            am = action_mask(agent.space, lane.state, stage=self.stage)
-            feat[bi], left[bi], right[bi], mask[bi] = enc
-            amask[bi] = am
-            keys[bi] = lane.key
-            encs[bi] = (enc, am)
-            prep_t[bi] = time.perf_counter() - t0
-
-        t0 = time.perf_counter()
-        if hasattr(agent, "act_batch"):
-            acts, logps, new_keys = agent.act_batch(
-                feat, left, right, mask, amask, keys, explore=self.explore)
-        else:                  # value-based agents (DQN) have no batch path
-            acts = np.zeros(B, np.int32)
-            logps = np.zeros(B, np.float32)
-            new_keys = keys
+        # profile span: what the lanes' stage boundaries wait for before
+        # their actions exist, i.e. encoding and the batched policy call
+        with span("lqrs.decide", lanes=len(decide)):
+            feat = np.zeros((B, MAX_NODES, F), np.float32)
+            left = np.zeros((B, MAX_NODES), np.int32)
+            right = np.zeros((B, MAX_NODES), np.int32)
+            mask = np.zeros((B, MAX_NODES), np.float32)
+            amask = np.zeros((B, d), np.float32)
+            amask[:, agent.space.noop_idx] = 1.0  # padded slots: noop
+            keys = np.zeros((B, 2), np.uint32)
+            encs, prep_t = {}, {}
             for lane in decide:
-                a, lp = agent.act(encs[lane.idx][0], encs[lane.idx][1],
-                                  explore=self.explore)
-                acts[lane.idx], logps[lane.idx] = a, lp
-        act_share = (time.perf_counter() - t0) / max(len(decide), 1)
+                bi = lane.idx
+                t0 = time.perf_counter()
+                with span("lqrs.encode", seq=lane.arrival.seq):
+                    enc = encode_state(lane.state, meta)
+                    am = action_mask(agent.space, lane.state,
+                                     stage=self.stage)
+                    feat[bi], left[bi], right[bi], mask[bi] = enc
+                    amask[bi] = am
+                    keys[bi] = lane.key
+                encs[bi] = (enc, am)
+                prep_t[bi] = time.perf_counter() - t0
+
+            t0 = time.perf_counter()
+            if hasattr(agent, "act_batch"):
+                acts, logps, new_keys = agent.act_batch(
+                    feat, left, right, mask, amask, keys,
+                    explore=self.explore)
+            else:              # value-based agents (DQN) have no batch path
+                acts = np.zeros(B, np.int32)
+                logps = np.zeros(B, np.float32)
+                new_keys = keys
+                for lane in decide:
+                    a, lp = agent.act(encs[lane.idx][0], encs[lane.idx][1],
+                                      explore=self.explore)
+                    acts[lane.idx], logps[lane.idx] = a, lp
+            act_share = (time.perf_counter() - t0) / max(len(decide), 1)
 
         for lane in decide:
             bi = lane.idx
             t0 = time.perf_counter()
             enc, am = encs[bi]
             a = int(acts[bi])
-            lane.key = new_keys[bi]
-            new_plan, r, extra = apply_action(agent.space, lane.state, a)
-            lane.traj.states.append(enc)
-            lane.traj.actions.append(a)
-            lane.traj.logps.append(float(logps[bi]))
-            lane.traj.masks.append(am)
-            lane.traj.rewards.append(r)
-            lane.traj.decoded.append(agent.space.decode(a))
-            lane.extra_plan += extra
-            if self.obs is not None:
-                # the decision lands at the suspended stage boundary
-                self.obs.on_decide(lane, lane.next_event,
-                                   lane.traj.decoded[-1], r)
+            with span("lqrs.apply", seq=lane.arrival.seq):
+                lane.key = new_keys[bi]
+                new_plan, r, extra = apply_action(agent.space, lane.state,
+                                                  a)
+                lane.traj.states.append(enc)
+                lane.traj.actions.append(a)
+                lane.traj.logps.append(float(logps[bi]))
+                lane.traj.masks.append(am)
+                lane.traj.rewards.append(r)
+                lane.traj.decoded.append(agent.space.decode(a))
+                lane.extra_plan += extra
+                if self.obs is not None:
+                    # the decision lands at the suspended stage boundary
+                    self.obs.on_decide(lane, lane.next_event,
+                                       lane.traj.decoded[-1], r)
             lane.traj.hook_seconds += (prep_t[bi] + act_share
                                        + time.perf_counter() - t0)
-            lane.state = lane.run.resume(new_plan)
+            self._resume(lane, new_plan)
             if lane.state is None:
                 self._finish(lane)
 
     # ----------------------------------------------------------- finishing
     def _finish(self, lane: _Lane) -> None:
         res = lane.run.result
+        # profile span: the query's epilogue, i.e. the terminal state's
+        # encoding in finalize_trajectory, the completion and every
+        # on_complete callback (online learning's harvest runs here)
         arr = lane.arrival
-        traj = finalize_trajectory(lane.traj, res, arr.query, self.est,
-                                   self.agent, self.cluster, self.agent.meta,
-                                   lane.extra_plan)
-        # virtual completion: simulated execution seconds only — the policy
-        # decision cost is a host metric (traj.hook_seconds / C_plan), kept
-        # off the clock so completion times are bit-reproducible
-        finish_t = lane.admit_t + res.latency
-        if self.obs is not None:
-            # annotate BEFORE recovery interception: a requeued/stashed
-            # attempt still records its own result and finish time
-            self.obs.on_run_finish(lane, res, finish_t)
-        if self.recovery is not None and \
-                self.recovery.on_finish(lane, traj, res, finish_t):
-            return                    # requeued as a retry, or hedge-stashed
-        comp = self._build_comp(arr, traj, res, lane.admit_t, finish_t,
-                                lane.idx, lane.hook_budget, lane.degraded,
-                                lane.predicted, memoized=lane.memoized)
-        self.completions.append(comp)
-        self._release(lane, finish_t)
-        for cb in self.on_complete:
-            cb(comp)
+        with span("lqrs.finish", seq=arr.seq):
+            traj = finalize_trajectory(lane.traj, res, arr.query, self.est,
+                                       self.agent, self.cluster,
+                                       self.agent.meta, lane.extra_plan)
+            # virtual completion: simulated execution seconds only — the
+            # policy decision cost is a host metric (traj.hook_seconds /
+            # C_plan), kept off the clock so completion times are
+            # bit-reproducible
+            finish_t = lane.admit_t + res.latency
+            if self.obs is not None:
+                # annotate BEFORE recovery interception: a requeued/stashed
+                # attempt still records its own result and finish time
+                self.obs.on_run_finish(lane, res, finish_t)
+            if self.recovery is not None and \
+                    self.recovery.on_finish(lane, traj, res, finish_t):
+                return                # retry requeued, or hedge-stashed
+            comp = self._build_comp(arr, traj, res, lane.admit_t, finish_t,
+                                    lane.idx, lane.hook_budget, lane.degraded,
+                                    lane.predicted, memoized=lane.memoized)
+            self.completions.append(comp)
+            self._release(lane, finish_t)
+            for cb in self.on_complete:
+                cb(comp)
 
     def _build_comp(self, arr: Arrival, traj: Trajectory, res: RunResult,
                     admit_t: float, finish_t: float, lane_idx: int,

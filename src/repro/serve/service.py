@@ -26,6 +26,7 @@ import numpy as np
 from repro.serve.cache import PartitionedStageCache, StageCache
 from repro.serve.scheduler import (Arrival, Completion, LaneScheduler,
                                    Rejection)
+from repro.spans import span
 from repro.sql.cbo import Estimator
 from repro.sql.cluster import ClusterModel
 
@@ -189,25 +190,32 @@ class QueryService:
     def run(self, stream: Sequence[Arrival]) \
             -> Tuple[List[Completion], ServiceStats]:
         """Serve `stream` to completion; returns (completions, stats)."""
-        self.scheduler = LaneScheduler(
-            self.db, self.est, self.agent, n_lanes=self.n_lanes,
-            explore=self.explore, cluster=self.cluster, policy=self.policy,
-            window=self.window, reuse_stages=self.reuse_stages,
-            admission=self.admission, recovery=self.recovery)
-        if self.obs is not None:
-            self.obs.attach(self.scheduler)
-        if self.plan_memory is not None:
-            self.plan_memory.attach(self.scheduler)
-        for h in self.hooks:
-            h.attach(self.scheduler)
-        if self.monitor is not None:
-            # last attacher: the monitor consumes the span trees the
-            # tracer's own on_complete assembles
-            self.monitor.attach(self.scheduler)
-        comps = self.scheduler.run(list(stream))
-        if self.monitor is not None:
-            self.monitor.finalize()
-        return comps, self._stats(comps)
+        stream = list(stream)
+        # profile span: everything the service does for one stream; its
+        # self time (outside the `lqrs.tick` passes) is the set-up below
+        # and the stats at the end
+        with span("lqrs.serve", queries=len(stream)):
+            self.scheduler = LaneScheduler(
+                self.db, self.est, self.agent, n_lanes=self.n_lanes,
+                explore=self.explore, cluster=self.cluster,
+                policy=self.policy, window=self.window,
+                reuse_stages=self.reuse_stages,
+                admission=self.admission, recovery=self.recovery)
+            if self.obs is not None:
+                self.obs.attach(self.scheduler)
+            if self.plan_memory is not None:
+                self.plan_memory.attach(self.scheduler)
+            for h in self.hooks:
+                h.attach(self.scheduler)
+            if self.monitor is not None:
+                # last attacher: the monitor consumes the span trees the
+                # tracer's own on_complete assembles
+                self.monitor.attach(self.scheduler)
+            comps = self.scheduler.run(stream)
+            if self.monitor is not None:
+                self.monitor.finalize()
+            stats = self._stats(comps)
+        return comps, stats
 
     def reset_stats(self, *, clear_entries: bool = False) -> None:
         """Zero the measurement state that otherwise ACCUMULATES across

@@ -22,6 +22,7 @@ from typing import Callable, Dict, Generator, List, Optional, Tuple
 import numpy as np
 
 from repro.serve.cache import StageCache
+from repro.spans import span
 from repro.sql.catalog import Database
 from repro.sql.cbo import Estimator
 from repro.sql.cluster import ClusterModel
@@ -467,20 +468,22 @@ class AdaptiveRun:
             if state.elapsed >= cluster.timeout:
                 raise QueryFailure("timeout", f"{state.elapsed:.1f}s")
 
+        def hits() -> int:
+            cs = ex.cache_stats
+            return 0 if cs is None else cs.hits
+
         def scan_charged(alias: str) -> MaterializedRel:
             """Scan + charge, with an optional trace note (cache hit
             detected by the stats delta around the executor call)."""
-            if trace is None:
+            h0, e0 = hits(), state.elapsed
+            # profile span: one base-table scan (or stage-cache hit)
+            with span("lqrs.exec.scan") as sp:
                 m, secs = ex.scan(query, alias)
+                hit = hits() > h0
+                sp.set_metadata(rows=int(m.nrows), hit=int(hit))
                 charge(secs)
-                return m
-            cs = ex.cache_stats
-            h0 = cs.hits if cs is not None else 0
-            e0 = state.elapsed
-            m, secs = ex.scan(query, alias)
-            charge(secs)
-            trace.scan(alias, e0, state.elapsed, m.nrows,
-                       cs is not None and cs.hits > h0)
+            if trace is not None:
+                trace.scan(alias, e0, state.elapsed, m.nrows, hit)
             return m
 
         try:
@@ -537,25 +540,23 @@ class AdaptiveRun:
                 # joining two multi-alias intermediates == bushy shape (§VI-B1)
                 if len(left_m.aliases) > 1 and len(right_m.aliases) > 1:
                     self._bushy = True
-                if trace is None:
-                    out, rec = ex.join(query, left_m, right_m, jn.conds,
-                                       method)
-                    charge(rec.seconds)
-                else:
+                if trace is not None:
                     # estimated-vs-actual rows only priced when tracing:
                     # the estimate is pure observation, never fed back
                     est_rows = state.est.join_rows(
                         query, left_m.aliases, float(left_m.nrows),
                         right_m.aliases, float(right_m.nrows))
-                    cs = ex.cache_stats
-                    h0 = cs.hits if cs is not None else 0
-                    e0 = state.elapsed
+                h0, e0 = hits(), state.elapsed
+                # profile span: one join stage (or stage-cache hit)
+                with span("lqrs.exec.join", method=method) as sp:
                     out, rec = ex.join(query, left_m, right_m, jn.conds,
                                        method)
+                    hit = hits() > h0
+                    sp.set_metadata(rows=int(out.nrows), hit=int(hit))
                     charge(rec.seconds)
+                if trace is not None:
                     trace.stage(out.aliases, method, e0, state.elapsed,
-                                out.nrows, est_rows, rec.shuffles,
-                                cs is not None and cs.hits > h0)
+                                out.nrows, est_rows, rec.shuffles, hit)
                 self._stages.append(rec)
                 self._tot_shuffles += rec.shuffles
                 self._tot_sbytes += rec.shuffle_bytes
