@@ -22,7 +22,10 @@ passed to `span(...)`; those known only at its end are added with
 - `hit`: 1 when the stage cache served the stage (the cache's own
   `hits` counter moved), else 0;
 - `rows`, `method`: the stage's output rows and join method, which say
-  why one executor span is long.
+  why one executor span is long;
+- `probe`: how a join that the stage cache did not serve matched its
+  keys ("unique", "dense" or "sorted", `sql.executor._join_indices`);
+  absent on a stage-cache hit.
 
 The names below are part of the program's interface: a profile reader
 finds the layers by them. Every name starts with `lqrs.`, and spans of one
@@ -61,7 +64,7 @@ SPANS = (
     ("lqrs.exec.scan", "AdaptiveRun: one base-table scan, or a stage-cache "
      "hit, and its charge [rows, hit]"),
     ("lqrs.exec.join", "AdaptiveRun: one join stage, or a stage-cache "
-     "hit, and its charge [rows, hit, method]"),
+     "hit, and its charge [rows, hit, method, probe]"),
 )
 
 _NAMES = frozenset(name for name, _ in SPANS)

@@ -7,9 +7,11 @@ sizes — the rule-based AQE switches SMJ<->BHJ exactly like Spark 3.x, and
 the *extension hook* (AQORA's planner extension, §VI) may rewrite the
 remaining plan (swap/lead/broadcast/cbo) before execution resumes.
 
-Joins compute exact match counts first (cheap: sort + searchsorted), so an
-exploding intermediate is detected and charged as OOM *without*
-materializing it — the same way a Spark executor dies before finishing.
+Joins compute exact match counts first (cheap: integer keys in a dense
+domain are counted by key with `bincount`, other keys by sort +
+searchsorted), so an exploding intermediate is detected and charged as OOM
+*without* materializing it — the same way a Spark executor dies before
+finishing.
 
 Latency is charged against `ClusterModel` (see cluster.py); cardinalities,
 shuffle counts and bytes are exact.
@@ -84,8 +86,10 @@ class RunResult:
 
 
 # ------------------------------------------------------------------ joins
-def _join_indices(lkey: np.ndarray, rkey: np.ndarray, cap: int):
-    """Exact inner-join row indices. Counts matches first; raises on blowup."""
+def _join_indices_sorted(lkey: np.ndarray, rkey: np.ndarray, cap: int):
+    """Exact inner-join row indices by a stable sort of the right key and
+    two binary searches of the left: any key dtype and domain. Counts
+    matches first; raises on blowup."""
     order = np.argsort(rkey, kind="stable")
     rs = rkey[order]
     lo = np.searchsorted(rs, lkey, "left")
@@ -99,6 +103,72 @@ def _join_indices(lkey: np.ndarray, rkey: np.ndarray, cap: int):
     offs = np.arange(total) - np.repeat(np.cumsum(cnt) - cnt, cnt)
     ridx = order[starts + offs]
     return lidx, ridx
+
+
+def _dense_domain(lkey: np.ndarray, rkey: np.ndarray) -> int:
+    """The size K of the key domain [0, K) when both keys are non-negative
+    integers in a domain small enough to address directly (tables of O(K)
+    stay O(input)); else 0."""
+    if not (len(lkey) and len(rkey)
+            and np.issubdtype(lkey.dtype, np.integer)
+            and np.issubdtype(rkey.dtype, np.integer)):
+        return 0
+    if min(int(lkey.min()), int(rkey.min())) < 0:
+        return 0
+    k = max(int(lkey.max()), int(rkey.max())) + 1
+    return k if k <= min(max(1 << 16, 2 * (len(lkey) + len(rkey))),
+                         1 << 32) else 0
+
+
+def _stable_order(key: np.ndarray, k: int) -> np.ndarray:
+    """`np.argsort(key, kind="stable")` for keys in [0, k), k <= 2**32, by
+    numpy's radix sort of 16-bit digits: one pass, or two LSD passes."""
+    if k <= 1 << 16:
+        return np.argsort(key.astype(np.uint16), kind="stable")
+    low = np.argsort((key & 0xFFFF).astype(np.uint16), kind="stable")
+    high = np.argsort((key[low] >> 16).astype(np.uint16), kind="stable")
+    return low[high]
+
+
+def _join_indices(lkey: np.ndarray, rkey: np.ndarray, cap: int):
+    """Exact inner-join row indices, left-major with each left row's
+    matches in increasing right index; counts matches first and raises on
+    blowup before the output is expanded. Returns (lidx, ridx, probe),
+    equal to `_join_indices_sorted`'s pair on every input.
+
+    Integer keys in a dense domain [0, K) are probed through tables
+    indexed by key (`probe` "unique": a position table when the right
+    keys are unique; "dense": match counts, and a radix order of the
+    right rows that match, otherwise); any other keys take the
+    sort-and-search path ("sorted")."""
+    k = _dense_domain(lkey, rkey)
+    if not k:
+        return (*_join_indices_sorted(lkey, rkey, cap), "sorted")
+    rkey = rkey.astype(np.intp, copy=False)
+    counts = np.bincount(rkey, minlength=k)
+    if counts.max() <= 1:
+        pos = np.full(k, -1, np.intp)
+        pos[rkey] = np.arange(len(rkey))
+        r_all = pos[lkey]
+        lidx = np.flatnonzero(r_all >= 0)
+        if len(lidx) > cap:
+            raise QueryFailure("oom",
+                               f"join output {len(lidx)} rows exceeds cap")
+        return lidx, r_all[lidx], "unique"
+    cnt = counts[lkey]
+    total = int(cnt.sum())
+    if total > cap:
+        raise QueryFailure("oom", f"join output {total} rows exceeds cap")
+    # order only the right rows whose key the left holds: a selective
+    # join sorts a fraction of its right side
+    held = np.bincount(lkey, minlength=k) > 0
+    sel = np.flatnonzero(held[rkey])
+    order = sel[_stable_order(rkey[sel], k)]
+    counts[~held] = 0
+    lo = (np.cumsum(counts) - counts)[lkey]
+    lidx = np.repeat(np.arange(len(lkey)), cnt)
+    offs = np.repeat(lo - (np.cumsum(cnt) - cnt), cnt) + np.arange(total)
+    return lidx, order[offs], "dense"
 
 
 def _needed_cols(query: Query, alias: str) -> List[str]:
@@ -147,6 +217,8 @@ class Executor:
                                    self._ENTRY_MAX_BYTES)
                 db._stage_cache = cache
             self._cache = cache
+        self.probe: Optional[str] = None   # last join's probe path, None
+        #   when the stage cache served it (see `_join_indices`)
 
     @property
     def cache_stats(self):
@@ -198,6 +270,7 @@ class Executor:
         else:
             key_l, key_r = (c0.right, c0.rcol), (c0.left, c0.lcol)
 
+        self.probe = None
         sig = None
         if self._cache is not None and left.sig is not None \
                 and right.sig is not None:
@@ -216,7 +289,8 @@ class Executor:
         else:
             lkey = left.columns[key_l]
             rkey = right.columns[key_r]
-            lidx, ridx = _join_indices(lkey, rkey, cl.materialize_cap)
+            lidx, ridx, self.probe = _join_indices(lkey, rkey,
+                                                   cl.materialize_cap)
             pre_total = len(lidx)
             # residual equality conditions
             keep = np.ones(len(lidx), bool)
@@ -553,6 +627,8 @@ class AdaptiveRun:
                                        method)
                     hit = hits() > h0
                     sp.set_metadata(rows=int(out.nrows), hit=int(hit))
+                    if ex.probe is not None:
+                        sp.set_metadata(probe=ex.probe)
                     charge(rec.seconds)
                 if trace is not None:
                     trace.stage(out.aliases, method, e0, state.elapsed,

@@ -11,6 +11,7 @@ from repro.sql import datagen, workloads
 from repro.sql.catalog import Database, Table, analyze
 from repro.sql.cbo import Estimator, cbo_plan, dp_join_order, greedy_join_order
 from repro.sql.cluster import ClusterModel
+from repro.sql import executor
 from repro.sql.executor import (Executor, QueryFailure, annotate_methods,
                                 run_adaptive, RuntimeState, planned_shuffles)
 from repro.sql.plans import (BHJ, SMJ, apply_broadcast, apply_lead,
@@ -179,6 +180,124 @@ def test_oom_on_exploding_join():
                        ClusterModel(materialize_cap=1_000_000))
     assert res.failed and res.failure_kind == "oom"
     assert res.latency == ClusterModel().timeout
+
+
+def _zipf_keys(rng, n, domain, a=1.2):
+    return ((rng.zipf(a, n) - 1) % domain).astype(np.int64)
+
+
+def _probe_case(name):
+    """(lkey, rkey, the probe path `_join_indices` must take)."""
+    rng = np.random.default_rng(PROBE_CASES.index(name))
+    if name == "unique":            # key-to-id join
+        return (_zipf_keys(rng, 5000, 900), rng.permutation(800), "unique")
+    if name == "zipf_dup":
+        return (_zipf_keys(rng, 3000, 400), _zipf_keys(rng, 4000, 500),
+                "dense")
+    if name == "left_outside_right":
+        return (rng.integers(0, 5000, 2000), _zipf_keys(rng, 700, 100),
+                "dense")
+    if name == "unique_wide":       # domain above 2**16, position table
+        return (rng.integers(0, 150_000, 50_000),
+                rng.choice(150_000, 60_000, replace=False), "unique")
+    if name == "dup_wide":          # domain above 2**16: two radix passes
+        return (_zipf_keys(rng, 50_000, 150_000, a=1.05),
+                rng.integers(0, 150_000, 60_000), "dense")
+    if name == "int32_uint16":
+        return (rng.integers(0, 300, 900).astype(np.int32),
+                rng.integers(0, 300, 700).astype(np.uint16), "dense")
+    if name == "empty_left":
+        return np.zeros(0, np.int64), _zipf_keys(rng, 500, 50), "sorted"
+    if name == "empty_right":
+        return _zipf_keys(rng, 500, 50), np.zeros(0, np.int64), "sorted"
+    if name == "sparse":            # domain far above the row counts
+        return (rng.integers(0, 40, 600) * 10**9,
+                rng.integers(0, 40, 500) * 10**9, "sorted")
+    if name == "negative":
+        return (rng.integers(-20, 20, 600), rng.integers(-20, 20, 500),
+                "sorted")
+    if name == "float":
+        return (rng.integers(0, 50, 600).astype(np.float64),
+                rng.integers(0, 50, 500).astype(np.float64), "sorted")
+    raise KeyError(name)
+
+
+PROBE_CASES = ("unique", "zipf_dup", "left_outside_right", "unique_wide",
+               "dup_wide", "int32_uint16", "empty_left", "empty_right",
+               "sparse", "negative", "float")
+
+
+@pytest.mark.parametrize("case", PROBE_CASES)
+def test_join_probe_equals_sort_and_search(case):
+    """The direct-address probe returns the reference's row indices
+    element for element, in the reference's order."""
+    lkey, rkey, path = _probe_case(case)
+    want_l, want_r = executor._join_indices_sorted(lkey, rkey, 10**9)
+    lidx, ridx, probe = executor._join_indices(lkey, rkey, 10**9)
+    assert probe == path
+    assert np.array_equal(lidx, want_l) and np.array_equal(ridx, want_r)
+    assert lidx.dtype == want_l.dtype and ridx.dtype == want_r.dtype
+
+
+@pytest.mark.parametrize("case", ("unique", "zipf_dup", "dup_wide",
+                                  "empty_left", "sparse"))
+def test_join_probe_raises_oom_where_the_reference_does(case):
+    """The cap admits exactly `total` matched rows on every path."""
+    lkey, rkey, path = _probe_case(case)
+    total = len(executor._join_indices_sorted(lkey, rkey, 10**9)[0])
+    lidx, _, probe = executor._join_indices(lkey, rkey, total)
+    assert probe == path and len(lidx) == total
+    for fn in (executor._join_indices, executor._join_indices_sorted):
+        with pytest.raises(QueryFailure) as err:
+            fn(lkey, rkey, total - 1)
+        assert err.value.kind == "oom"
+        assert f"join output {total} rows" in str(err.value)
+
+
+def test_join_probe_changes_no_run(job_db, estimator, job_workload,
+                                   monkeypatch):
+    """JOB-like queries run through the executor give the same stages,
+    rows and latency with the probe as with sort-and-search alone."""
+    queries = job_workload.test[:6]
+
+    def runs():
+        return [run_adaptive(job_db, q, syntactic_plan(q), estimator,
+                             ClusterModel(), reuse_stages=False)
+                for q in queries]
+
+    seen = []
+    probe = executor._join_indices
+
+    def spy(lkey, rkey, cap):
+        out = probe(lkey, rkey, cap)
+        seen.append(out[2])
+        return out
+
+    monkeypatch.setattr(executor, "_join_indices", spy)
+    fast = runs()
+    assert {"unique", "dense"} <= set(seen)
+    monkeypatch.setattr(
+        executor, "_join_indices",
+        lambda l, r, cap: (*executor._join_indices_sorted(l, r, cap),
+                           "sorted"))
+    for a, b in zip(fast, runs()):
+        assert a.stages == b.stages
+        assert a.latency == b.latency and a.failed == b.failed
+        assert a.final_plan == b.final_plan
+
+
+def test_join_probe_is_named_on_cache_misses_only():
+    """`Executor.probe` names the path of the join it ran, and is None
+    when the stage cache served the join."""
+    db = _tiny_db(3)
+    q = _tiny_query()
+    ex = Executor(db)
+    a, _ = ex.scan(q, "a")
+    b, _ = ex.scan(q, "b")
+    first, _ = ex.join(q, b, a, q.conds[:1], SMJ)     # b.a_id = a.id
+    assert ex.probe == "unique"
+    again, _ = ex.join(q, b, a, q.conds[:1], SMJ)
+    assert ex.probe is None and again.nrows == first.nrows
 
 
 def test_partitioning_reuse_reduces_shuffles(job_db, estimator):

@@ -154,6 +154,11 @@ def test_exec_span_hits_are_the_stage_cache_hits(traced):
     assert sum(x["hit"] for x in execs) == hits
     joins = [s[3] for s in spans if s[0] == "lqrs.exec.join"]
     assert {x["method"] for x in joins} <= {"SMJ", "BHJ"}
+    # the probe path is recorded on the joins the executor ran, only
+    assert all(x["probe"] in ("unique", "dense", "sorted")
+               for x in joins if not x["hit"])
+    assert not any("probe" in x for x in joins if x["hit"])
+    assert not all(x["hit"] for x in joins)
     assert len(joins) >= sum(len(c.result.stages) for c in comps)
 
 
