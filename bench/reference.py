@@ -6,10 +6,15 @@ enough to run after every window:
 
 * `JoinCounter` - the number of rows an inner equi-join over a connected,
   acyclic set of relations returns, with each relation's filters applied
-  first. It never materialises a row: it passes per-key row counts from
-  the leaves of the join tree to its root (a counting Yannakakis pass),
-  so its cost is linear in the table sizes whatever join order a plan
-  would choose. Any plan of an exact engine gives this many rows.
+  first. Two relations may be joined on several columns at once (a
+  composite key, as TPC-DS joins a return to its sale): the conditions
+  between one pair of relations are one edge of the join tree, and it is
+  the edges that must form a tree. It never materialises a row: it passes
+  per-key row counts from the leaves of the join tree to its root (a
+  counting Yannakakis pass), a composite key first numbered in a domain
+  both sides share, so its cost is near linear in the table sizes
+  whatever join order a plan would choose. Any plan of an exact engine
+  gives this many rows.
 * `tree_net` - the tree-CNN actor's logits, or the critic's value: three
   binary tree convolutions (self, left child, right child weights plus a
   bias, leaky ReLU, padding re-zeroed), a residual around the third, a
@@ -60,7 +65,8 @@ class JoinCounter:
     `tables` maps a table name to its columns (name -> 1-D int array).
     `relations` is a sequence of (alias, table, filters) with filters as
     (column, op, value) triples; `conds` of (left_alias, left_col,
-    right_alias, right_col) equalities."""
+    right_alias, right_col) equalities. Several conditions between the
+    same two aliases join them on a composite key."""
 
     def __init__(self, tables: Mapping[str, Mapping[str, np.ndarray]],
                  relations: Sequence[Tuple[str, str, Sequence]],
@@ -88,19 +94,58 @@ class JoinCounter:
         n = len(next(iter(cols.values())))
         return _column(cols, n, column)[self._selected(alias)]
 
+    def _edges(self, aliases: List[str]) -> Dict[Tuple[str, str], List]:
+        """The conditions inside `aliases` grouped by unordered pair of
+        relations: (a, b) with a < b -> [(a's column, b's column), ...].
+        The pairs must form a spanning tree of `aliases`."""
+        inside = set(aliases)
+        edges: Dict[Tuple[str, str], List[Tuple[str, str]]] = {}
+        for la, lc, ra, rc in self._conds:
+            if la in inside and ra in inside:
+                if la > ra:
+                    la, lc, ra, rc = ra, rc, la, lc
+                edges.setdefault((la, ra), []).append((lc, rc))
+        group = {a: a for a in aliases}      # union-find over the pairs
+
+        def root(a):
+            while group[a] != a:
+                a = group[a]
+            return a
+        for a, b in edges:
+            ra, rb = root(a), root(b)
+            if ra == rb:
+                raise ValueError(f"join graph over {aliases} has a cycle "
+                                 f"through {a} and {b}")
+            group[ra] = rb
+        if len({root(a) for a in aliases}) != 1:
+            raise ValueError(f"join graph over {aliases} is not connected")
+        return edges
+
+    def _edge_keys(self, a: str, a_cols: Sequence[str], p: str,
+                   p_cols: Sequence[str]) -> Tuple[np.ndarray, np.ndarray]:
+        """The join keys of `a` and `p` on one edge. A composite key's
+        tuples are numbered in one domain over both sides' tuples."""
+        if len(a_cols) == 1:
+            return self._key(a, a_cols[0]), self._key(p, p_cols[0])
+        a_tup = np.stack([self._key(a, c) for c in a_cols], axis=1)
+        p_tup = np.stack([self._key(p, c) for c in p_cols], axis=1)
+        _, codes = np.unique(np.concatenate([a_tup, p_tup]), axis=0,
+                             return_inverse=True)
+        codes = codes.reshape(-1)
+        return codes[:len(a_tup)], codes[len(a_tup):]
+
     def count(self, aliases: Iterable[str]) -> int:
         """Rows of the inner join of `aliases` under the conditions among
-        them. The conditions must form a spanning tree of the set."""
+        them. The pairs of relations the conditions join must form a
+        spanning tree of the set; one pair may be joined on several
+        columns."""
         aliases = sorted(set(aliases))
-        inside = set(aliases)
-        edges = [c for c in self._conds if c[0] in inside and c[2] in inside]
-        if len(edges) != len(aliases) - 1:
-            raise ValueError(f"join graph over {aliases} is not a tree "
-                             f"({len(edges)} conditions)")
-        adj: Dict[str, List[Tuple[str, str, str]]] = {a: [] for a in aliases}
-        for la, lc, ra, rc in edges:
-            adj[la].append((ra, lc, rc))    # (neighbour, my col, its col)
-            adj[ra].append((la, rc, lc))
+        adj: Dict[str, List[Tuple[str, List[str], List[str]]]] = {
+            a: [] for a in aliases}
+        for (a, b), cols in self._edges(aliases).items():
+            a_cols, b_cols = [c for c, _ in cols], [c for _, c in cols]
+            adj[a].append((b, a_cols, b_cols))   # (neighbour, my cols,
+            adj[b].append((a, b_cols, a_cols))   #  its cols)
         root = aliases[0]
         order, parent, seen = [], {root: None}, {root}
         stack = [root]
@@ -112,16 +157,13 @@ class JoinCounter:
                     seen.add(b)
                     parent[b] = (a, mine, theirs)
                     stack.append(b)
-        if len(seen) != len(aliases):
-            raise ValueError(f"join graph over {aliases} is not connected")
         # w[a][i]: rows of the subtree under a that join with a's row i
         w = {a: np.ones(len(self._selected(a)), np.float64) for a in aliases}
         for a in reversed(order):           # children before parents
             if parent[a] is None:
                 continue
-            p, p_col, a_col = parent[a]
-            a_key = self._key(a, a_col)
-            p_key = self._key(p, p_col)
+            p, p_cols, a_cols = parent[a]
+            a_key, p_key = self._edge_keys(a, a_cols, p, p_cols)
             if len(a_key) == 0 or len(p_key) == 0:
                 w[p] = np.zeros(len(p_key))
                 continue
@@ -138,7 +180,8 @@ class JoinCounter:
 
 def query_counter(tables, query) -> JoinCounter:
     """A `JoinCounter` over one query object (`relations` with `alias`,
-    `table`, `filters`; `conds` with `left`, `lcol`, `right`, `rcol`)."""
+    `table`, `filters`; `conds` with `left`, `lcol`, `right`, `rcol`, where
+    several conditions between two aliases make one composite-key edge)."""
     rels = [(r.alias, r.table, [(f.column, f.op, tuple(f.value))
                                 for f in r.filters]) for r in query.relations]
     conds = [(c.left, c.lcol, c.right, c.rcol) for c in query.conds]

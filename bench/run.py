@@ -136,6 +136,11 @@ def run(plan: dict, seed: int, seconds: float, trace: bool,
         out["breakdown"] = {"device_ops": record["trace"]["device_ops"],
                             "idle_gaps": record["trace"]["idle_gaps"]}
         say("idle by span:", json.dumps(record["trace"]["idle_by_span"]))
+        self_s = record["trace"]["self_s"]
+        say("program spans:", json.dumps({
+            "self_s": self_s, "counts": record["trace"]["span_counts"],
+            "self_share_of_window": sum(self_s.values())
+            / record["trace"]["window_s"]}))
     out["checks"] = {k: {"value": v, "limit": lims[k]}
                      for k, v in compared.items()}
     for k, v in compared.items():
