@@ -17,10 +17,11 @@ mix. Each is a JSON file found by name:
 A configuration names the program's data generator (`"data"`, a
 function of `repro.sql.datagen`) and its workload (`"workload"."name"`,
 a template set of `repro.sql.workloads`), so a new one is a file.
-`--seed` spaces the arrivals (and draws the training episodes); the
-data, the set-up policy and the query pool come from the configuration's
-own seeds, so that every seed does the same work and the end-to-end
-numbers of two seeds differ by the system's noise, not by the draw.
+`--seed` draws the answer check's sample and the training episodes; the
+data, the set-up policy, the query pool, its arrivals and each query's
+PRNG key come from the configuration's own seeds, so that every seed
+does the same work and the end-to-end numbers of two seeds differ by
+the system's noise, not by the draw.
 """
 from __future__ import annotations
 
@@ -29,6 +30,7 @@ import hashlib
 import importlib.util
 import json
 import math
+import resource
 import shutil
 import sys
 import time
@@ -413,6 +415,21 @@ def query_pool(cfg: Dict, n_chunks: int, chunk: int) -> List[List]:
             for _ in range(n_chunks)]
 
 
+def pool_streams(cfg: Dict, traffic: Dict, n_chunks: int) -> List[List]:
+    """The run's chunks of arrivals on one modelled clock, with Poisson
+    gaps drawn from the pool seed, the same in every run: arrivals drawn
+    from `--seed` changed the work (their interleaving decides what the
+    stage cache evicts), and so did the queries' PRNG keys, which a
+    frozen greedy policy never reads, by the host's speed alone."""
+    pool = query_pool(cfg, n_chunks, traffic["chunk_queries"])
+    rng = np.random.default_rng(sub_seed(cfg["pool_seed"], 1))
+    out, t = [], 0.0
+    for chunk in pool:
+        stream, t = chunk_stream(chunk, rng, t, traffic["rate_qps"])
+        out.append(stream)
+    return out
+
+
 def chunk_stream(pool_chunk, rng: np.random.Generator, t0: float,
                  rate: float):
     """One chunk in the pool's order with Poisson gaps from `rng` at `rate`
@@ -448,6 +465,12 @@ def online_hooks(world: World, traffic: Dict, store_dir: Path):
     return [harvester, learner], learner
 
 
+def _usage():
+    """This process's user and system CPU seconds so far."""
+    ru = resource.getrusage(resource.RUSAGE_SELF)
+    return ru.ru_utime, ru.ru_stime
+
+
 def p95_ms(seconds: List[float]) -> Optional[float]:
     """The 95th percentile, in ms, linear between order statistics."""
     return float(np.percentile(np.asarray(seconds) * 1e3, 95)) \
@@ -461,10 +484,8 @@ def serve(world: World, cfg: Dict, traffic: Dict, seed: int,
     `window_units(seconds, chunk_seconds)` chunks as the window."""
     import jax
     rec = world.rec
-    chunk = traffic["chunk_queries"]
     n_window = window_units(seconds, traffic["chunk_seconds"])
-    pool = query_pool(cfg, n_window + 1, chunk)
-    rng = np.random.default_rng(sub_seed(seed, 1))
+    streams = pool_streams(cfg, traffic, n_window + 1)
     hooks, learner = [], None
     if traffic.get("online"):
         hooks, learner = online_hooks(world, traffic,
@@ -475,10 +496,8 @@ def serve(world: World, cfg: Dict, traffic: Dict, seed: int,
     warm_policy(world.agent, cfg["lanes"], traffic["explore"])
     if learner is not None:
         warm_update(learner.agent, traffic["online"]["sample_size"])
-    t_model, raised = 0.0, []
-    stream, t_model = chunk_stream(pool[0], rng, t_model,
-                                   traffic["rate_qps"])
-    svc.run(stream)
+    raised = []
+    svc.run(streams[0])
     world.phases["warmup_s"] = time.perf_counter() - t0
     learn_warm = None if learner is None else learner.stats.as_dict()
     cache_before = svc.cache.stats.as_dict()
@@ -492,11 +511,9 @@ def serve(world: World, cfg: Dict, traffic: Dict, seed: int,
     rec.live = True
     t_start = time.perf_counter()
     with annotate("bench.window"):
-        for k in range(1, n_window + 1):
-            stream, t_model = chunk_stream(pool[k], rng, t_model,
-                                           traffic["rate_qps"])
+        for stream in streams[1:]:
             attempted += len(stream)
-            t_chunk, c_chunk = time.perf_counter(), time.process_time()
+            t_chunk, u_chunk = time.perf_counter(), _usage()
             try:
                 with annotate("bench.chunk"):
                     got, _ = svc.run(stream)
@@ -505,7 +522,7 @@ def serve(world: World, cfg: Dict, traffic: Dict, seed: int,
                 raised.append(traceback.format_exc())
             decide_sizes += list(svc.scheduler.decide_sizes)
             chunk_s.append((time.perf_counter() - t_chunk,
-                            time.process_time() - c_chunk))
+                            *(b - a for a, b in zip(u_chunk, _usage()))))
     window_s = time.perf_counter() - t_start
     rec.live = False
     if trace_dir is not None:
@@ -517,13 +534,14 @@ def serve(world: World, cfg: Dict, traffic: Dict, seed: int,
              for k2, v in svc.cache.stats.as_dict().items()
              if k2 in ("hits", "misses", "evictions")}
     h = hashlib.sha256()
-    for ch in pool:
-        query_digest([q for q, _ in ch], h)
+    for stream in streams:
+        query_digest([a.query for a in stream], h)
     calls = rec.policy_calls
     summary = {
         "window_s": window_s, "chunks": n_window,
-        "chunk_s": [round(w, 3) for w, _ in chunk_s],
-        "chunk_cpu_s": [round(c, 3) for _, c in chunk_s],
+        "chunk_s": [round(w, 3) for w, *_ in chunk_s],
+        "chunk_cpu_s": [round(u + s, 3) for _, u, s in chunk_s],
+        "chunk_sys_s": [round(s, 3) for _, _, s in chunk_s],
         "attempted": attempted,
         "completed": len(comps), "raised_chunks": len(raised),
         "modelled_failures": sum(c.result.failed for c in comps),

@@ -12,9 +12,11 @@ enough to run after every window:
   the edges that must form a tree. It never materialises a row: it passes
   per-key row counts from the leaves of the join tree to its root (a
   counting Yannakakis pass), a composite key first numbered in a domain
-  both sides share, so its cost is near linear in the table sizes
-  whatever join order a plan would choose. Any plan of an exact engine
-  gives this many rows.
+  both sides share (`tuple_codes`), so its cost is near linear in the
+  table sizes whatever join order a plan would choose. Any plan of an
+  exact engine gives this many rows. Counters that share a memo select
+  each (table, filters) and number each composite edge once between
+  them.
 * `tree_net` - the tree-CNN actor's logits, or the critic's value: three
   binary tree convolutions (self, left child, right child weights plus a
   bias, leaky ReLU, padding re-zeroed), a residual around the third, a
@@ -29,7 +31,8 @@ lower-precision control.
 """
 from __future__ import annotations
 
-from typing import Dict, Iterable, List, Mapping, Sequence, Tuple
+import math
+from typing import Dict, Iterable, List, Mapping, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -59,6 +62,35 @@ def _column(columns: Mapping[str, np.ndarray], nrows: int,
     raise KeyError(name)
 
 
+def _unique_rows(cols: Sequence[np.ndarray]) -> np.ndarray:
+    """`tuple_codes` by sorting the tuples themselves, for any columns."""
+    _, codes = np.unique(np.stack(cols, axis=1), axis=0, return_inverse=True)
+    return codes.reshape(-1)
+
+
+def tuple_codes(cols: Sequence[np.ndarray]) -> np.ndarray:
+    """Dense codes 0..u-1 of the rows of k equally long columns, in the
+    lexicographic order of their tuples: equal tuples, equal codes. Integer
+    columns whose spans (max - min + 1) multiply to under 2**63 are packed
+    into one int64 in mixed radix, the first column most significant, and
+    numbered by a 1-D sort; other columns sort the tuples themselves. Both
+    give the same codes."""
+    n = len(cols[0])
+    if n == 0:
+        return np.zeros(0, np.int64)
+    if not all(np.can_cast(c.dtype, np.int64) for c in cols):
+        return _unique_rows(cols)
+    lows = [int(c.min()) for c in cols]
+    spans = [int(c.max()) - lo + 1 for c, lo in zip(cols, lows)]
+    if math.prod(spans) >= 2 ** 63:
+        return _unique_rows(cols)
+    packed = np.zeros(n, np.int64)
+    for c, lo, span in zip(cols, lows, spans):
+        packed = packed * span + (c.astype(np.int64) - lo)
+    _, codes = np.unique(packed, return_inverse=True)
+    return codes.reshape(-1)
+
+
 class JoinCounter:
     """Row counts of joins over one query's relations.
 
@@ -66,27 +98,35 @@ class JoinCounter:
     `relations` is a sequence of (alias, table, filters) with filters as
     (column, op, value) triples; `conds` of (left_alias, left_col,
     right_alias, right_col) equalities. Several conditions between the
-    same two aliases join them on a composite key."""
+    same two aliases join them on a composite key.
+
+    `memo` is a dict the caller may share between counters over the same,
+    unchanging `tables`: it keeps the rows each (table, filters) selects
+    and each composite edge's numbered keys, by both sides' (table,
+    filters, columns), so that such an edge is numbered once among all of
+    them. Without one, the counter keeps its own."""
 
     def __init__(self, tables: Mapping[str, Mapping[str, np.ndarray]],
                  relations: Sequence[Tuple[str, str, Sequence]],
-                 conds: Sequence[Tuple[str, str, str, str]]):
+                 conds: Sequence[Tuple[str, str, str, str]],
+                 memo: Optional[Dict] = None):
         self._tables = tables
         self._rel = {a: (t, tuple(f)) for a, t, f in relations}
         self._conds = list(conds)
-        self._rows: Dict[str, np.ndarray] = {}
+        self._memo = {} if memo is None else memo
 
     def _selected(self, alias: str) -> np.ndarray:
         """Indices of the rows of `alias` that pass its filters."""
-        if alias not in self._rows:
+        key = ("rows",) + self._rel[alias]
+        if key not in self._memo:
             table, filters = self._rel[alias]
             cols = self._tables[table]
             n = len(next(iter(cols.values())))
             keep = np.ones(n, bool)
             for column, op, value in filters:
                 keep &= _filter_mask(_column(cols, n, column), op, value)
-            self._rows[alias] = np.flatnonzero(keep)
-        return self._rows[alias]
+            self._memo[key] = np.flatnonzero(keep)
+        return self._memo[key]
 
     def _key(self, alias: str, column: str) -> np.ndarray:
         table, _ = self._rel[alias]
@@ -124,15 +164,20 @@ class JoinCounter:
     def _edge_keys(self, a: str, a_cols: Sequence[str], p: str,
                    p_cols: Sequence[str]) -> Tuple[np.ndarray, np.ndarray]:
         """The join keys of `a` and `p` on one edge. A composite key's
-        tuples are numbered in one domain over both sides' tuples."""
+        tuples are numbered in one domain over both sides' tuples, once
+        per memo."""
         if len(a_cols) == 1:
             return self._key(a, a_cols[0]), self._key(p, p_cols[0])
-        a_tup = np.stack([self._key(a, c) for c in a_cols], axis=1)
-        p_tup = np.stack([self._key(p, c) for c in p_cols], axis=1)
-        _, codes = np.unique(np.concatenate([a_tup, p_tup]), axis=0,
-                             return_inverse=True)
-        codes = codes.reshape(-1)
-        return codes[:len(a_tup)], codes[len(a_tup):]
+        side_a = self._rel[a] + (tuple(a_cols),)
+        side_p = self._rel[p] + (tuple(p_cols),)
+        if ("edge", side_a, side_p) not in self._memo:
+            n = len(self._selected(a))
+            codes = tuple_codes([
+                np.concatenate([self._key(a, ca), self._key(p, cp)])
+                for ca, cp in zip(a_cols, p_cols)])
+            self._memo["edge", side_a, side_p] = codes[:n], codes[n:]
+            self._memo["edge", side_p, side_a] = codes[n:], codes[:n]
+        return self._memo["edge", side_a, side_p]
 
     def count(self, aliases: Iterable[str]) -> int:
         """Rows of the inner join of `aliases` under the conditions among
@@ -178,14 +223,15 @@ class JoinCounter:
         return int(total)
 
 
-def query_counter(tables, query) -> JoinCounter:
+def query_counter(tables, query, memo: Optional[Dict] = None) -> JoinCounter:
     """A `JoinCounter` over one query object (`relations` with `alias`,
     `table`, `filters`; `conds` with `left`, `lcol`, `right`, `rcol`, where
-    several conditions between two aliases make one composite-key edge)."""
+    several conditions between two aliases make one composite-key edge),
+    sharing `memo` (see `JoinCounter`) where one is given."""
     rels = [(r.alias, r.table, [(f.column, f.op, tuple(f.value))
                                 for f in r.filters]) for r in query.relations]
     conds = [(c.left, c.lcol, c.right, c.rcol) for c in query.conds]
-    return JoinCounter(tables, rels, conds)
+    return JoinCounter(tables, rels, conds, memo)
 
 
 # ------------------------------------------------------------ the policy

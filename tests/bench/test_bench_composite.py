@@ -101,3 +101,26 @@ def test_an_answer_off_by_a_row_is_wrong(served):
     comps[0].result.stages[-1].out_rows += 1
     got = checks.answers(db, comps, len(queries), np.random.default_rng(0))
     assert got["wrong_answers"] >= 1
+
+
+def test_a_shared_memo_counts_the_same(served):
+    """Counters sharing one memo, as `checks.answers` builds them, count
+    every stage of every served query as counters with their own do, and
+    as the program's exact executor did."""
+    db, queries, comps = served
+    tables = {n: t.columns for n, t in db.tables.items()}
+    memo = {}
+    for c in comps:
+        shared = reference.query_counter(tables, c.query, memo)
+        alone = reference.query_counter(tables, c.query)
+        for s in c.result.stages:
+            assert shared.count(s.covered) == alone.count(s.covered) \
+                == s.out_rows, (c.query.name, s.covered)
+    assert sum(k[0] == "edge" for k in memo) >= 2
+
+
+def test_the_check_reports_its_seconds(served):
+    db, queries, comps = served
+    got = checks.answers(db, comps, len(queries), np.random.default_rng(1))
+    assert 0 < got["answers_s"] < 60
+    assert "answers_s" not in checks.limits()

@@ -46,25 +46,29 @@ def test_other_mixes_run(traffic, metrics):
     assert all(m["value"] > 0 for m in out["metrics"].values())
 
 
-def test_stack_config_serves():
+def test_stack_config_serves(capsys):
     plan = plan_for("stack", "serve")
     out = RUN.run(plan, 3, 1.0, False, time.perf_counter())
     assert out["correct"] is True and out["attempted"] >= 16
+    # the answer check's own seconds are printed, not compared
+    checked = [ln for ln in capsys.readouterr().out.splitlines()
+               if ln.startswith("checked: ")]
+    assert json.loads(checked[-1][len("checked: "):])["answers_s"] > 0
+    assert "answers_s" not in out["checks"]
 
 
 def test_same_seed_same_inputs():
-    """The seed spaces the arrivals; the queries and their order are the
-    pool's, the same for every seed."""
-    cfg = tiny(harness.cell_plan(harness.load_spec(), "job.serve"))["config"]
-    pool = harness.query_pool(cfg, 2, 16)
-    a, _ = harness.chunk_stream(pool[1], np.random.default_rng(9), 0.0, 2.0)
-    b, _ = harness.chunk_stream(pool[1], np.random.default_rng(9), 0.0, 2.0)
-    c, _ = harness.chunk_stream(pool[1], np.random.default_rng(10), 0.0,
-                                2.0)
-    key = lambda s: [(x.t, x.query.name, x.seed) for x in s]  # noqa: E731
-    assert key(a) == key(b) and key(a) != key(c)
-    assert [x.query.name for x in a] == [x.query.name for x in c]
-    assert [x.t for x in a] != [x.t for x in c]
+    """Every run serves the same queries, with the same PRNG keys, at the
+    same arrivals, in the pool's order, whatever its seed."""
+    plan = tiny(harness.cell_plan(harness.load_spec(), "job.serve"))
+    cfg, mix = plan["config"], plan["traffic"]
+    a, b = (harness.pool_streams(cfg, mix, n) for n in (3, 5))
+    key = lambda ss: [[(x.t, x.query.name, x.seed) for x in s]  # noqa: E731
+                      for s in ss]
+    assert key(a) == key(b)[:3]
+    t = [x.t for s in a for x in s]
+    assert t == sorted(t) and len(set(t)) == len(t) == 3 * 16
+    assert len({x.seed for s in a for x in s}) == 3 * 16
 
 
 @pytest.mark.parametrize("seconds,nominal,units", [

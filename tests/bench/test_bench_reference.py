@@ -180,3 +180,177 @@ def test_single_column_counts_are_unchanged(bench, make):
             assert new.count(order[:k]) == old.count(order[:k]), (q.name, k)
             checked += 1
     assert checked > 100
+
+
+# -------------------------------------------- composite keys, packed
+class _UniqueRowsCounter(reference.JoinCounter):
+    """The counter with the composite numbering it had before keys were
+    packed: `np.unique(axis=0)` over both sides' stacked tuples, for every
+    edge of more than one column, in every count."""
+
+    def _edge_keys(self, a, a_cols, p, p_cols):
+        if len(a_cols) == 1:
+            return self._key(a, a_cols[0]), self._key(p, p_cols[0])
+        a_tup = np.stack([self._key(a, c) for c in a_cols], axis=1)
+        p_tup = np.stack([self._key(p, c) for c in p_cols], axis=1)
+        _, codes = np.unique(np.concatenate([a_tup, p_tup]), axis=0,
+                             return_inverse=True)
+        codes = codes.reshape(-1)
+        return codes[:len(a_tup)], codes[len(a_tup):]
+
+
+# the key columns' values: a small domain (many duplicate tuples), one
+# that holds negative values, and one whose spans multiply past 2**63
+VALUES = {"small": lambda rng, n: rng.integers(0, 5, n),
+          "negative": lambda rng, n: rng.integers(-4, 3, n),
+          "huge": lambda rng, n: rng.choice(
+              np.array([-2 ** 62, -1, 0, 7, 2 ** 62 - 1]), n)}
+
+
+def _keyed_world(seed: int, values: str, empty: bool = False):
+    """Four tables of a few hundred rows, three key columns of `values`
+    each and a filter column; with `empty`, c's filter selects no row."""
+    rng = np.random.default_rng(seed)
+    tables = {f"t{a}": {**{f"k{i}": VALUES[values](rng, int(n))
+                           for i in range(3)},
+                        "f": rng.integers(0, 10, int(n))}
+              for a, n in zip(ALIASES, rng.integers(200, 400, 4))}
+    filters = [[("f", "<=", (7,))], [], [("f", ">=", (10 if empty else 2,))],
+               [("f", "in", (1, 3, 4, 8))]]
+    return tables, [(a, f"t{a}", f) for a, f in zip(ALIASES, filters)]
+
+
+def _calls(monkeypatch, name: str) -> List[int]:
+    """A list that grows by one at each call of `reference.<name>`."""
+    calls: List[int] = []
+    real = getattr(reference, name)
+    monkeypatch.setattr(reference, name,
+                        lambda *a: calls.append(1) or real(*a))
+    return calls
+
+
+@pytest.mark.parametrize("cols", [1, 2, 3])
+@pytest.mark.parametrize("values", sorted(VALUES))
+def test_tuple_codes_are_unique_rows_codes(monkeypatch, values, cols):
+    rng = np.random.default_rng(cols)
+    tup = [VALUES[values](rng, 500) for _ in range(cols)]
+    fell_back = _calls(monkeypatch, "_unique_rows")
+    got = reference.tuple_codes(tup)
+    _, want = np.unique(np.stack(tup, axis=1), axis=0, return_inverse=True)
+    np.testing.assert_array_equal(got, want.reshape(-1))
+    assert bool(fell_back) == (values == "huge")
+    assert len(reference.tuple_codes([t[:0] for t in tup])) == 0
+
+
+@pytest.mark.parametrize("empty", [False, True])
+@pytest.mark.parametrize("widths", [(2, 2, 2), (3, 3, 3), (3, 2, 3)])
+@pytest.mark.parametrize("shape", sorted(SHAPES))
+@pytest.mark.parametrize("values", sorted(VALUES))
+def test_packed_counts_equal_unique_rows_counts(monkeypatch, values, shape,
+                                                widths, empty):
+    """Every connected subset of chains and stars whose edges join on two
+    or three columns counts what the `np.unique(axis=0)` numbering counts,
+    on keys with duplicates, negative values, an empty side, and spans
+    past 2**63 (which take the fallback)."""
+    fell_back = _calls(monkeypatch, "_unique_rows")
+    conds = _conds(shape, widths)
+    nonzero = 0
+    for seed in range(2):
+        tables, rels = _keyed_world(seed, values, empty)
+        new = reference.JoinCounter(tables, rels, conds)
+        old = _UniqueRowsCounter(tables, rels, conds)
+        for subset in _connected_subsets(SHAPES[shape]):
+            want = old.count(subset)
+            assert new.count(subset) == want, (seed, subset)
+            nonzero += len(subset) > 1 and want > 0
+    assert nonzero >= 2     # an empty c leaves a chain only a-b to match
+    assert bool(fell_back) == (values == "huge")
+
+
+# ---------------------------------------------------- the shared memo
+def _pool_counts(tables, queries, memo):
+    """Each query's join of each growing prefix of its relations in
+    breadth-first order, by counters that share `memo` (None: each its
+    own)."""
+    out = []
+    for q in queries:
+        counter = reference.query_counter(tables, q, memo)
+        adj = q.adjacency()
+        order, todo = [q.relations[0].alias], [q.relations[0].alias]
+        while todo:
+            for b in adj[todo.pop(0)]:
+                if b not in order:
+                    order.append(b)
+                    todo.append(b)
+        out += [counter.count(order[:k]) for k in range(1, len(order) + 1)]
+    return out
+
+
+@pytest.mark.parametrize("bench,make", [("job", "make_job_like"),
+                                        ("stack", "make_stack_like")])
+def test_a_shared_memo_counts_the_same(bench, make):
+    from repro.sql import datagen, workloads
+    db = getattr(datagen, make)(scale=0.05, seed=0)
+    tables = {name: t.columns for name, t in db.tables.items()}
+    wl = workloads.make_workload(bench, n_train=12, n_test_per_template=1,
+                                 seed=7)
+    queries = wl.train + wl.test
+    memo = {}
+    shared = _pool_counts(tables, queries, memo)
+    assert shared == _pool_counts(tables, queries, None)
+    assert len(shared) > 100 and any(shared)
+    # one selection per distinct (table, filters), not one per relation
+    rels = {(r.table, tuple((f.column, f.op, tuple(f.value))
+                            for f in r.filters))
+            for q in queries for r in q.relations}
+    assert sum(k[0] == "rows" for k in memo) == len(rels)
+
+
+def _sales_returns(seed: int = 3):
+    """Sales and returns joined on (ticket, item), a return copying a
+    sale's pair, and an item dimension."""
+    rng = np.random.default_rng(seed)
+    sales = {"ticket": np.arange(2000) // 5,
+             "item": rng.integers(0, 60, 2000),
+             "qty": rng.integers(1, 6, 2000)}
+    src = rng.integers(0, 2000, 400)
+    returns = {"ticket": sales["ticket"][src], "item": sales["item"][src],
+               "qty": rng.integers(1, 6, 400)}
+    return {"sales": sales, "returns": returns,
+            "item": {"cat": rng.integers(0, 5, 60)}}
+
+
+def _sales_returns_counter(tables, memo, cat, qty=None,
+                           cls=reference.JoinCounter):
+    ret_filters = [("qty", ">=", (qty,))] if qty else []
+    return cls(tables, [
+        ("ss", "sales", []), ("sr", "returns", ret_filters),
+        ("i", "item", [("cat", "<=", (cat,))])], [
+        ("ss", "ticket", "sr", "ticket"), ("ss", "item", "sr", "item"),
+        ("ss", "item", "i", "id")], memo)
+
+
+def test_an_unfiltered_composite_edge_is_numbered_once(monkeypatch):
+    numbered = _calls(monkeypatch, "tuple_codes")
+    tables, memo = _sales_returns(), {}
+    for cat in range(4):            # other dimension filters, and both
+        for subset in (["ss", "sr"], ["sr", "ss", "i"], ["i", "ss"]):
+            got = _sales_returns_counter(tables, memo, cat).count(subset)
+            assert got == _sales_returns_counter(tables, None, cat).count(
+                subset)             # directions of the composite edge
+    assert sum(numbered) == 1 + 4 * 2   # the shared edge once; memo-less
+    #                                     counters number it for themselves
+    assert _sales_returns_counter(tables, memo, 0).count(["ss", "sr"]) > 0
+
+
+def test_a_filtered_side_is_numbered_apart(monkeypatch):
+    numbered = _calls(monkeypatch, "tuple_codes")
+    tables, memo = _sales_returns(), {}
+    counts = {}
+    for qty in (None, 3, None, 3, 5):
+        counts[qty] = _sales_returns_counter(tables, memo, 2, qty).count(
+            ["ss", "sr", "i"])
+        assert counts[qty] == _sales_returns_counter(
+            tables, None, 2, qty, _UniqueRowsCounter).count(["ss", "sr", "i"])
+    assert sum(numbered) == 3       # one per distinct filter on returns
+    assert counts[None] > counts[3] > counts[5] > 0
