@@ -25,7 +25,11 @@ passed to `span(...)`; those known only at its end are added with
   why one executor span is long;
 - `probe`: how a join that the stage cache did not serve matched its
   keys ("unique", "dense" or "sorted", `sql.executor._join_indices`);
-  absent on a stage-cache hit.
+  absent on a stage-cache hit;
+- `cols`, `dropped`: on a join that the stage cache did not serve, the
+  key columns its output carries (those a later join of the query reads,
+  `sql.executor._live_cols`; 0 at the query's last join) and the input
+  columns it left out; absent on a stage-cache hit.
 
 The names below are part of the program's interface: a profile reader
 finds the layers by them. Every name starts with `lqrs.`, and spans of one
@@ -64,7 +68,7 @@ SPANS = (
     ("lqrs.exec.scan", "AdaptiveRun: one base-table scan, or a stage-cache "
      "hit, and its charge [rows, hit]"),
     ("lqrs.exec.join", "AdaptiveRun: one join stage, or a stage-cache "
-     "hit, and its charge [rows, hit, method, probe]"),
+     "hit, and its charge [rows, hit, method, probe, cols, dropped]"),
 )
 
 _NAMES = frozenset(name for name, _ in SPANS)

@@ -171,6 +171,21 @@ def _join_indices(lkey: np.ndarray, rkey: np.ndarray, cap: int):
     return lidx, order[offs], "dense"
 
 
+def _live_cols(query: Query,
+               aliases: frozenset) -> Tuple[Tuple[str, str], ...]:
+    """The key columns an intermediate over `aliases` must carry: an end
+    of a condition inside it whose other end lies outside, so that a later
+    join reads it. Depends on the query alone, never on the plan; sorted,
+    so it keys the stage cache."""
+    live = set()
+    for c in query.conds:
+        if c.left in aliases and c.right not in aliases:
+            live.add((c.left, c.lcol))
+        elif c.right in aliases and c.left not in aliases:
+            live.add((c.right, c.rcol))
+    return tuple(sorted(live))
+
+
 def _needed_cols(query: Query, alias: str) -> List[str]:
     cols = set()
     for c in query.conds:
@@ -189,7 +204,10 @@ class Executor:
     every episode; the serving layer sees repeated template hits — skip
     the numpy work and only re-charge the modeled latency. Latency,
     shuffle accounting and OOM checks are always recomputed against THIS
-    run's cluster, so results are bit-identical with the cache off.
+    run's cluster, so results are bit-identical with the cache off. A join
+    keeps only the key columns a later join reads (`_live_cols`), and its
+    signature names them, so two queries that share a join's inputs but
+    read different columns later never share its entry.
 
     The cache itself is a `serve.cache.StageCache` shared via the database
     object: LRU eviction under a byte budget, and every signature embeds
@@ -219,6 +237,9 @@ class Executor:
             self._cache = cache
         self.probe: Optional[str] = None   # last join's probe path, None
         #   when the stage cache served it (see `_join_indices`)
+        self.cols: Optional[int] = None    # last join's output columns and
+        self.dropped: Optional[int] = None  # input columns it left out; None
+        #   when the stage cache served it
 
     @property
     def cache_stats(self):
@@ -270,11 +291,16 @@ class Executor:
         else:
             key_l, key_r = (c0.right, c0.rcol), (c0.left, c0.lcol)
 
-        self.probe = None
+        self.probe = self.cols = self.dropped = None
+        aliases = left.aliases | right.aliases
+        # only the keys a later join reads are gathered: the probe key and
+        # the residual conditions are read from the inputs, and the last
+        # stage of a query carries its row count alone
+        live = _live_cols(query, aliases)
         sig = None
         if self._cache is not None and left.sig is not None \
                 and right.sig is not None:
-            sig = ("j", left.sig, right.sig, tuple(conds))
+            sig = ("j", left.sig, right.sig, tuple(conds), live)
         hit = self._cache.get(sig) if sig is not None else None
         if hit is not None:
             out_cols, nrows, pre_total = hit
@@ -283,8 +309,7 @@ class Executor:
             if pre_total > cl.materialize_cap:
                 raise QueryFailure(
                     "oom", f"join output {pre_total} rows exceeds cap")
-            out = MaterializedRel(left.aliases | right.aliases,
-                                  dict(out_cols), nrows,
+            out = MaterializedRel(aliases, dict(out_cols), nrows,
                                   left.width + right.width, sig=sig)
         else:
             lkey = left.columns[key_l]
@@ -302,11 +327,12 @@ class Executor:
                 keep &= left.columns[la][lidx] == right.columns[ra][ridx]
             if not keep.all():
                 lidx, ridx = lidx[keep], ridx[keep]
-            out_cols = {k: v[lidx] for k, v in left.columns.items()}
-            out_cols.update({k: v[ridx] for k, v in right.columns.items()})
-            out = MaterializedRel(left.aliases | right.aliases, out_cols,
-                                  len(lidx), left.width + right.width,
-                                  sig=sig)
+            out_cols = {k: (left.columns[k][lidx] if k[0] in left.aliases
+                            else right.columns[k][ridx]) for k in live}
+            self.cols = len(out_cols)
+            self.dropped = len(left.columns) + len(right.columns) - self.cols
+            out = MaterializedRel(aliases, out_cols, len(lidx),
+                                  left.width + right.width, sig=sig)
             if sig is not None:
                 nbytes = sum(v.nbytes for v in out_cols.values())
                 self._cache.put(sig, (dict(out_cols), len(lidx), pre_total),
@@ -628,7 +654,8 @@ class AdaptiveRun:
                     hit = hits() > h0
                     sp.set_metadata(rows=int(out.nrows), hit=int(hit))
                     if ex.probe is not None:
-                        sp.set_metadata(probe=ex.probe)
+                        sp.set_metadata(probe=ex.probe, cols=ex.cols,
+                                        dropped=ex.dropped)
                     charge(rec.seconds)
                 if trace is not None:
                     trace.stage(out.aliases, method, e0, state.elapsed,

@@ -4,15 +4,20 @@ operator switching, OOM/timeout semantics, shuffle accounting.
 
 Property-style tests use seeded sweeps (hypothesis is not installed in this
 offline container — see DESIGN.md §Testing note)."""
+import itertools
+
 import numpy as np
 import pytest
 
+from repro.core.actions import ActionSpace, action_mask, apply_action
+from repro.serve.cache import StageCache
 from repro.sql import datagen, workloads
 from repro.sql.catalog import Database, Table, analyze
 from repro.sql.cbo import Estimator, cbo_plan, dp_join_order, greedy_join_order
 from repro.sql.cluster import ClusterModel
 from repro.sql import executor
-from repro.sql.executor import (Executor, QueryFailure, annotate_methods,
+from repro.sql.executor import (AdaptiveRun, Executor, MaterializedRel,
+                                QueryFailure, StageRecord, annotate_methods,
                                 run_adaptive, RuntimeState, planned_shuffles)
 from repro.sql.plans import (BHJ, SMJ, apply_broadcast, apply_lead,
                              apply_swap, build_left_deep, is_bushy, joins,
@@ -298,6 +303,219 @@ def test_join_probe_is_named_on_cache_misses_only():
     assert ex.probe == "unique"
     again, _ = ex.join(q, b, a, q.conds[:1], SMJ)
     assert ex.probe is None and again.nrows == first.nrows
+
+
+def _full_gather_join(self, query, left, right, conds, method):
+    """`Executor.join` as it was before joins kept only the live key
+    columns: every column of both inputs is gathered into the output, and
+    the stage-cache signature names no columns."""
+    cl = self.cluster
+    c0 = conds[0]
+    if c0.left in left.aliases:
+        key_l, key_r = (c0.left, c0.lcol), (c0.right, c0.rcol)
+    else:
+        key_l, key_r = (c0.right, c0.rcol), (c0.left, c0.lcol)
+    self.probe = None
+    sig = None
+    if self._cache is not None and left.sig is not None \
+            and right.sig is not None:
+        sig = ("j", left.sig, right.sig, tuple(conds))
+    hit = self._cache.get(sig) if sig is not None else None
+    if hit is not None:
+        out_cols, nrows, pre_total = hit
+        if pre_total > cl.materialize_cap:
+            raise QueryFailure(
+                "oom", f"join output {pre_total} rows exceeds cap")
+        out = MaterializedRel(left.aliases | right.aliases,
+                              dict(out_cols), nrows,
+                              left.width + right.width, sig=sig)
+    else:
+        lkey = left.columns[key_l]
+        rkey = right.columns[key_r]
+        lidx, ridx, self.probe = executor._join_indices(
+            lkey, rkey, cl.materialize_cap)
+        pre_total = len(lidx)
+        keep = np.ones(len(lidx), bool)
+        for c in conds[1:]:
+            if c.left in left.aliases:
+                la, ra = (c.left, c.lcol), (c.right, c.rcol)
+            else:
+                la, ra = (c.right, c.rcol), (c.left, c.lcol)
+            keep &= left.columns[la][lidx] == right.columns[ra][ridx]
+        if not keep.all():
+            lidx, ridx = lidx[keep], ridx[keep]
+        out_cols = {k: v[lidx] for k, v in left.columns.items()}
+        out_cols.update({k: v[ridx] for k, v in right.columns.items()})
+        out = MaterializedRel(left.aliases | right.aliases, out_cols,
+                              len(lidx), left.width + right.width,
+                              sig=sig)
+        if sig is not None:
+            nbytes = sum(v.nbytes for v in out_cols.values())
+            self._cache.put(sig, (dict(out_cols), len(lidx), pre_total),
+                            nbytes)
+    shuffles = 0
+    shuffle_bytes = 0.0
+    if method == SMJ:
+        t = cl.stage_overhead
+        for side, key in ((left, key_l), (right, key_r)):
+            if side.partitioned_on != key:
+                shuffles += 1
+                shuffle_bytes += side.bytes
+                t += cl.shuffle_time(side.bytes)
+        t += cl.smj_cpu(left.nrows, right.nrows, out.nrows)
+        out.partitioned_on = key_l
+    else:
+        build, probe = ((left, right) if left.bytes <= right.bytes
+                        else (right, left))
+        if cl.broadcast_oom(build.bytes):
+            raise QueryFailure("oom",
+                               f"broadcast build {build.bytes/1e6:.1f} MB")
+        t = cl.stage_overhead + cl.broadcast_time(build.bytes)
+        t += cl.bhj_cpu(build.nrows, probe.nrows, out.nrows)
+        out.partitioned_on = probe.partitioned_on
+    rec = StageRecord(out.aliases, method, out.nrows, out.bytes,
+                      shuffles, shuffle_bytes, t)
+    return out, rec
+
+
+POOL = 24
+
+
+@pytest.fixture(scope="module", params=["job", "stack"])
+def pool(request, job_db, stack_db):
+    """The first queries of a configuration's serving pool, on its
+    database."""
+    db = job_db if request.param == "job" else stack_db
+    queries = list(itertools.islice(
+        workloads.query_stream(request.param, seed=1009), POOL))
+    return db, Estimator(db, db.stats), queries
+
+
+def _rewriting_hook(queries):
+    """A deterministic hook that rewrites the remaining plan at every
+    stage boundary: a legal cbo/lead/swap/broadcast action picked from the
+    step and the query's name."""
+    space = ActionSpace(max(q.n_relations for q in queries),
+                        families=("cbo", "lead", "swap", "broadcast"))
+
+    def hook(state):
+        legal = np.flatnonzero(action_mask(space, state))
+        pick = (3 * state.step + len(state.query.name)) % len(legal)
+        return apply_action(space, state, int(legal[pick]))[0]
+    return hook
+
+
+def _serve_pool(db, est, queries, hook, passes=2):
+    """Every query run `passes` times on one shared stage cache, so the
+    later passes are served from it; the runs' results in order."""
+    cache = StageCache(Executor._CACHE_MAX_BYTES, Executor._ENTRY_MAX_BYTES)
+    out = []
+    for _ in range(passes):
+        for q in queries:
+            run = AdaptiveRun(db, q, syntactic_plan(q), est, ClusterModel(),
+                              max_hook_steps=q.n_relations if hook else 0,
+                              cache=cache)
+            st = run.start()
+            while st is not None:
+                st = run.resume(hook(st))
+            out.append(run.result)
+    return out
+
+
+@pytest.mark.parametrize("plans", ["syntactic", "rewritten"])
+def test_live_column_joins_change_no_run(pool, plans, monkeypatch):
+    """Joins that carry only the key columns a later join reads give the
+    same stages, latency and failures as joins that carry every column,
+    on syntactic plans and on plans a hook rewrites at each boundary."""
+    db, est, queries = pool
+    hook = _rewriting_hook(queries) if plans == "rewritten" else None
+    live = _serve_pool(db, est, queries, hook)
+    monkeypatch.setattr(Executor, "join", _full_gather_join)
+    full = _serve_pool(db, est, queries, hook)
+    assert sum(len(r.stages) for r in live) > 2 * len(queries)
+    for a, b in zip(live, full):
+        assert a.stages == b.stages
+        assert a.latency == b.latency
+        assert (a.failed, a.failure_kind) == (b.failed, b.failure_kind)
+        assert a.final_plan == b.final_plan
+
+
+def _read_later(query, aliases):
+    """The (alias, column) ends of the query's conditions that lie inside
+    `aliases` while the other end lies outside."""
+    ends = set()
+    for c in query.conds:
+        for (a, col), other in (((c.left, c.lcol), c.right),
+                                ((c.right, c.rcol), c.left)):
+            if a in aliases and other not in aliases:
+                ends.add((a, col))
+    return ends
+
+
+def test_joins_carry_exactly_the_live_columns(pool, monkeypatch):
+    """Every intermediate, computed or served from the stage cache, holds
+    exactly the key columns a later join of its query reads; a query's
+    last stage holds none."""
+    db, est, queries = pool
+    outs = []
+    join = Executor.join
+
+    def spy(self, query, left, right, conds, method):
+        out, rec = join(self, query, left, right, conds, method)
+        outs.append((query, out, rec))
+        return out, rec
+
+    monkeypatch.setattr(Executor, "join", spy)
+    _serve_pool(db, est, queries, _rewriting_hook(queries))
+    last = 0
+    for q, out, rec in outs:
+        assert set(out.columns) == _read_later(q, out.aliases)
+        assert all(len(v) == out.nrows for v in out.columns.values())
+        assert out.nrows == rec.out_rows
+        if len(out.aliases) == q.n_relations:
+            assert out.columns == {}
+            last += 1
+    assert last >= len(queries)
+
+
+def test_stage_cache_keeps_apart_joins_that_feed_other_columns(job_db,
+                                                               estimator):
+    """Two queries share a join's inputs and conditions, but the next
+    join reads `t.id` in one and `mc.movie_id` in the other: the second
+    must compute the join, not take the first's entry, and both must
+    count the rows a direct count gives."""
+    rels = (Relation("t", "title", (Filter("production_year", ">=",
+                                           (2005,)),)),
+            Relation("mc", "movie_companies"), Relation("mi", "movie_info"))
+    first = JoinCond("t", "id", "mc", "movie_id")
+    q1 = Query("by_t", rels, (first, JoinCond("t", "id", "mi", "movie_id")))
+    q2 = Query("by_mc", rels, (first,
+                               JoinCond("mc", "movie_id", "mi", "movie_id")))
+    t = job_db.table("title")
+    ids = t.columns["id"][t.columns["production_year"] >= 2005]
+    n = lambda name: np.bincount(job_db.table(name).columns["movie_id"],
+                                 minlength=t.nrows)
+    expected = int((n("movie_companies") * n("movie_info"))[ids].sum())
+    cache = StageCache(Executor._CACHE_MAX_BYTES, Executor._ENTRY_MAX_BYTES)
+    probes = []
+    join = Executor.join
+
+    def spy(self, *args):
+        out = join(self, *args)
+        probes.append(self.probe)
+        return out
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(Executor, "join", spy)
+        for q in (q1, q2, q1):
+            run = AdaptiveRun(job_db, q, syntactic_plan(q), estimator,
+                              ClusterModel(), max_hook_steps=0, cache=cache)
+            run.start()
+            assert not run.result.failed
+            assert run.result.stages[-1].out_rows == expected > 0
+    # q2 computes both of its joins; q1 run again is served both
+    assert probes[2] is not None and probes[3] is not None
+    assert probes[4:] == [None, None]
 
 
 def test_partitioning_reuse_reduces_shuffles(job_db, estimator):

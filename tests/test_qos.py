@@ -164,8 +164,10 @@ def test_flood_cannot_evict_other_tenants_cache(job_workload, agent):
     ws = svc.cache.bytes
     assert sigs and ws > 0
 
+    # the flood's partition has room for one of its 24 title scans, not
+    # for all of them
     reg = TenantRegistry([TenantSpec("victim", cache_bytes=2 * ws),
-                          TenantSpec("flood", cache_bytes=ws // 2)])
+                          TenantSpec("flood", cache_bytes=ws)])
     db = fresh_db()
     svc = QueryService(db, agent, est=Estimator(db, db.stats), n_lanes=2,
                        tenants=reg)

@@ -159,6 +159,12 @@ def test_exec_span_hits_are_the_stage_cache_hits(traced):
                for x in joins if not x["hit"])
     assert not any("probe" in x for x in joins if x["hit"])
     assert not all(x["hit"] for x in joins)
+    # so are the key columns a join carried and those it left out
+    missed = [x for x in joins if not x["hit"]]
+    assert all(x["cols"] >= 0 and x["dropped"] >= 0 for x in missed)
+    assert not any("cols" in x or "dropped" in x for x in joins if x["hit"])
+    assert any(x["cols"] == 0 for x in missed)       # a query's last join
+    assert any(x["dropped"] > 0 for x in missed)
     assert len(joins) >= sum(len(c.result.stages) for c in comps)
 
 
