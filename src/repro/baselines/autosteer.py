@@ -27,6 +27,7 @@ import numpy as np
 
 from repro.core import nets
 from repro.optim import AdamWConfig, adamw_init, adamw_update
+from repro.serve.cache import StageCache
 from repro.sql.cbo import Estimator, cbo_plan
 from repro.sql.cluster import ClusterModel
 from repro.sql.executor import RunResult, annotate_methods, run_adaptive
@@ -52,6 +53,7 @@ class AutoSteerOptimizer:
     def __init__(self, db, est: Estimator, seed: int = 0,
                  cluster: ClusterModel = ClusterModel()):
         self.db, self.est, self.cluster = db, est, cluster
+        self.cache = StageCache()    # toggle sets replay stages of `db`
         k = jax.random.split(jax.random.PRNGKey(seed), 1)[0]
         self.net = nets.init_mlp_head(k, QFEAT + len(RULES), 64, 1)
         self.opt = adamw_init(self.net)
@@ -86,7 +88,7 @@ class AutoSteerOptimizer:
         plan = annotate_methods(plan, query, self.est, cluster)
         return run_adaptive(self.db, query, plan, self.est, cluster,
                             aqe_switching="aqe_switch" not in disabled,
-                            plan_time=t_plan)
+                            plan_time=t_plan, cache=self.cache)
 
     # ------------------------------------------------------------- choose
     def _predict(self, query, disabled) -> float:

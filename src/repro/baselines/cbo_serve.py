@@ -8,9 +8,9 @@ no-ops at every later boundary. It is what a system that "just re-runs
 the optimizer at admission" would do, which makes it the natural probe
 for statistics quality: its plans are a deterministic function of
 `db.stats`, so serving metrics under this policy isolate the stale-stats
-premise from learned-policy effects. `benchmarks/bench_drift.py` uses it
-to price re-ANALYZE policies (the drift control plane) against the
-paper's never-refresh baseline without an RL confound.
+premise from learned-policy effects: it prices re-ANALYZE policies (the
+drift control plane) against the paper's never-refresh baseline without
+an RL confound.
 
 Deterministic and host-cheap by construction: no parameters, no RNG
 consumption (keys pass through untouched), one numpy argmax-free branch

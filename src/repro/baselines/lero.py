@@ -22,6 +22,7 @@ import numpy as np
 
 from repro.core import nets
 from repro.optim import AdamWConfig, adamw_init, adamw_update
+from repro.serve.cache import StageCache
 from repro.sql.cbo import Estimator, cbo_plan
 from repro.sql.cluster import ClusterModel
 from repro.sql.executor import RunResult, annotate_methods, run_adaptive
@@ -77,6 +78,7 @@ class LeroOptimizer:
     def __init__(self, db, est: Estimator, seed: int = 0,
                  cluster: ClusterModel = ClusterModel()):
         self.db, self.est, self.cluster = db, est, cluster
+        self.cache = StageCache()    # candidates replay stages of `db`
         k = jax.random.split(jax.random.PRNGKey(seed), 1)[0]
         self.net = nets.init_mlp_head(k, FEAT_DIM, 64, 1)
         self.opt = adamw_init(self.net)
@@ -130,7 +132,7 @@ class LeroOptimizer:
     def run(self, query) -> RunResult:
         plan, t_plan, _ = self.choose(query)
         return run_adaptive(self.db, query, plan, self.est, self.cluster,
-                            plan_time=t_plan)
+                            plan_time=t_plan, cache=self.cache)
 
     # ------------------------------------------------------------ training
     def train_episode(self, query, explore_all: bool = True):
@@ -140,7 +142,8 @@ class LeroOptimizer:
         plans, _ = self.candidates(query)
         results = []
         for p in plans[:4]:              # bound exploration cost
-            r = run_adaptive(self.db, query, p, self.est, self.cluster)
+            r = run_adaptive(self.db, query, p, self.est, self.cluster,
+                             cache=self.cache)
             results.append((plan_features(p, query, self.est), r.latency))
         for i in range(len(results)):
             for j in range(len(results)):

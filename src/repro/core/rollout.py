@@ -80,7 +80,7 @@ def finalize_trajectory(traj: Trajectory, res: RunResult, query, est,
 def rollout(db, query, est: Estimator, agent, *, stage: int = 3,
             explore: bool = True,
             cluster: Optional[ClusterModel] = None,
-            key=None, reuse_stages: bool = True) -> Trajectory:
+            key=None, cache=None) -> Trajectory:
     cluster = cluster if cluster is not None else ClusterModel()
     traj = Trajectory()
     meta = agent.meta
@@ -110,6 +110,6 @@ def rollout(db, query, est: Estimator, agent, *, stage: int = 3,
     plan0 = syntactic_plan(query)
     res = run_adaptive(db, query, plan0, est, cluster, hook=hook,
                        max_hook_steps=agent.cfg.max_steps,
-                       plan_time=0.0, reuse_stages=reuse_stages)
+                       plan_time=0.0, cache=cache)
     return finalize_trajectory(traj, res, query, est, agent, cluster, meta,
                                extra_plan[0])

@@ -26,6 +26,7 @@ from repro.core.agent import AgentConfig, AqoraAgent
 from repro.core.encoding import WorkloadMeta
 from repro.core.rollout import rollout
 from repro.core.vec_rollout import rollout_batch
+from repro.serve.cache import StageCache
 from repro.sql.catalog import Database
 from repro.sql.cbo import Estimator
 from repro.sql.cluster import ClusterModel
@@ -67,6 +68,7 @@ def train_agent(db: Database, workload: Workload, *,
         agent = AqoraAgent(meta, cfg, seed=seed)
     est = est or Estimator(db, db.stats)
     rng = np.random.default_rng(seed)
+    cache = StageCache()      # one per call: every episode replays from it
     logs: List[EpisodeLog] = []
 
     def log_progress(ep_start, n_eps, stage, m):
@@ -88,7 +90,7 @@ def train_agent(db: Database, workload: Workload, *,
         if batch_size <= 1:
             q = workload.train[int(rng.integers(len(workload.train)))]
             traj = rollout(db, q, est, agent, stage=stage, explore=True,
-                           cluster=cluster)
+                           cluster=cluster, cache=cache)
             m = agent.ppo_update(traj)
             logs.append(EpisodeLog(ep, q.name, traj.t_execute, traj.failed,
                                    traj.decoded, traj.rewards,
@@ -102,7 +104,7 @@ def train_agent(db: Database, workload: Workload, *,
               for _ in range(bs)]
         seeds = [int(rng.integers(2 ** 31)) for _ in range(bs)]
         trajs = rollout_batch(db, qs, est, agent, stage=stage, explore=True,
-                              cluster=cluster, seeds=seeds)
+                              cluster=cluster, seeds=seeds, cache=cache)
         if hasattr(agent, "ppo_update_batch"):
             m = agent.ppo_update_batch(trajs)
         else:                              # e.g. DQN: per-trajectory replay
@@ -133,11 +135,12 @@ def evaluate(db: Database, queries, agent: AqoraAgent, *,
     """
     cluster = cluster if cluster is not None else ClusterModel()
     est = est or Estimator(db, db.stats)
+    cache = StageCache()      # one per call, shared by its queries
     if policy is not None:
         from repro.serve.scheduler import Arrival, LaneScheduler
         sched = LaneScheduler(db, est, agent, n_lanes=max(batch_size, 1),
                               stage=3, explore=False, cluster=cluster,
-                              policy=policy)
+                              policy=policy, cache=cache)
         comps = sched.run([Arrival(0.0, query=q, seed=i)
                            for i, q in enumerate(queries)])
         trajs = [c.traj for c in comps]
@@ -145,10 +148,11 @@ def evaluate(db: Database, queries, agent: AqoraAgent, *,
         trajs = []
         for i in range(0, len(queries), batch_size):
             trajs += rollout_batch(db, queries[i:i + batch_size], est, agent,
-                                   stage=3, explore=False, cluster=cluster)
+                                   stage=3, explore=False, cluster=cluster,
+                                   cache=cache)
     else:
         trajs = [rollout(db, q, est, agent, stage=3, explore=False,
-                         cluster=cluster) for q in queries]
+                         cluster=cluster, cache=cache) for q in queries]
     out = []
     for q, traj in zip(queries, trajs):
         r = traj.result

@@ -30,11 +30,13 @@ from repro.sql.cluster import ClusterModel
 def rollout_batch(db, queries: Sequence, est: Estimator, agent, *,
                   stage: int = 3, explore: bool = True,
                   cluster: Optional[ClusterModel] = None,
-                  seeds: Optional[Sequence] = None) -> List[Trajectory]:
+                  seeds: Optional[Sequence] = None,
+                  cache=None) -> List[Trajectory]:
     """Run `queries` in lockstep; returns one Trajectory per query.
 
     `seeds[i]` keys lane i's action sampling (defaults to 0..B-1); a serial
     `rollout(db, queries[i], ..., key=seeds[i])` reproduces lane i exactly.
+    `cache` is the caller's stage cache (None: no reuse).
     """
     B = len(queries)
     if seeds is None:
@@ -42,7 +44,7 @@ def rollout_batch(db, queries: Sequence, est: Estimator, agent, *,
     assert len(seeds) == B, "one seed per lane"
     sched = LaneScheduler(db, est, agent, n_lanes=B, stage=stage,
                           explore=explore, cluster=cluster,
-                          policy="lockstep")
+                          policy="lockstep", cache=cache)
     comps = sched.run([Arrival(0.0, query=q, seed=s)
                        for q, s in zip(queries, seeds)])
     return [c.traj for c in comps]

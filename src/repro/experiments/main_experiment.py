@@ -1,7 +1,7 @@
 """Main experiment runner: AQORA + 3 baselines on 3 benchmarks (§VII).
 
-Writes results/aqora/<bench>.json incrementally (resumable); benchmarks/*
-read these files to print the paper's tables/figures. Run:
+Writes results/aqora/<bench>.json incrementally (resumable; `results/`
+holds run outputs only and is not tracked). Run:
 
   PYTHONPATH=src python -m repro.experiments.main_experiment --bench job
   PYTHONPATH=src python -m repro.experiments.main_experiment --all

@@ -16,8 +16,7 @@ streams over one workload) by fixing the others' sub-seeds.
 `World.meta` is the `WorkloadMeta` the serving agent encodes against;
 for cross-schema serving (train on world A, serve world B) keep A's
 meta — B's unseen tables encode as all-zero bits (§V-B2), which is
-exactly the generalization question `benchmarks/bench_generalize.py`
-measures.
+exactly the generalization question.
 """
 from __future__ import annotations
 

@@ -105,12 +105,15 @@ class BackgroundLearner:
 
     def attach(self, scheduler) -> None:
         self._sched = scheduler
-        if self.store is not None and \
-                getattr(self.store, "obs", None) is None:
-            # wire the store's observability sink to the scheduler's
-            # tracer (QueryService attaches obs before hooks, so it is
-            # already installed here; None stays None)
-            self.store.obs = getattr(scheduler, "obs", None)
+        if self.store is not None:
+            if getattr(self.store, "obs", None) is None:
+                # wire the store's observability sink to the scheduler's
+                # tracer (QueryService attaches obs before hooks, so it is
+                # already installed here; None stays None)
+                self.store.obs = getattr(scheduler, "obs", None)
+            # probe runs share the stage cache of the scheduler they gate
+            # for: it is the cache kept over the database they probe
+            self.store.cache = scheduler.cache
         if self.curriculum is not None:
             scheduler.stage = self.curriculum.stage
             self._gate_explore()

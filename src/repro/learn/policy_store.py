@@ -48,17 +48,17 @@ log = logging.getLogger("repro.learn")
 class PolicyStore:
     def __init__(self, directory, probe: Sequence, *, rel_tol: float = 0.0,
                  abs_tol: float = 1e-6, keep_last: int = 5,
-                 mode: str = "gate", probe_reuse_stages: bool = True):
-        """probe_reuse_stages=True lets probe runs share the serving stage
-        cache: results are bit-identical either way (the cache invariant),
-        and repeated gates then cost near-zero host time; set False for
-        fully cache-isolated evaluation."""
+                 mode: str = "gate", cache=None):
+        """`cache` is the stage cache probe runs read and fill (None: no
+        reuse); `learn.BackgroundLearner.attach` sets it to the serving
+        scheduler's. Results are bit-identical either way (the cache
+        invariant); a shared cache makes repeated gates cheap."""
         assert mode in ("gate", "shadow"), mode
         self.ckpt = Checkpointer(directory, keep_last=keep_last)
         self.probe = list(probe)
         self.rel_tol, self.abs_tol = rel_tol, abs_tol
         self.mode = mode
-        self.probe_reuse_stages = probe_reuse_stages
+        self.cache = cache
         self.versions: List[Dict] = []      # committed (accepted) versions
         self.gate_log: List[Dict] = []      # every gate verdict
         self.serving_step: Optional[int] = None
@@ -105,8 +105,7 @@ class PolicyStore:
         candidate serve under the same tighter mask — the gate bounds
         capability, not the exact restricted-serving distribution."""
         lats = [rollout(db, q, est, agent, stage=3, explore=False,
-                        cluster=cluster,
-                        reuse_stages=self.probe_reuse_stages).result.latency
+                        cluster=cluster, cache=self.cache).result.latency
                 for q in self.probe]
         return float(np.mean(lats)) if lats else 0.0
 

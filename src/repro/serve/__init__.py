@@ -35,12 +35,11 @@ Four cooperating pieces, each in its own module:
                 instantiated from the JOB/ExtJOB/STACK templates, with
                 optional interleaved delta batches.
 
-  service.py    Façade tying it together: `QueryService.run(stream)`
-                installs the cache, runs the scheduler, and reports
-                throughput (qps), p50/p99 latency (with the queue-wait /
-                in-lane breakdown), and cache hit rate — the numbers
-                `benchmarks/bench_serve.py` persists to
-                results/BENCH_serve.json. With a tenant registry the
+  service.py    Façade tying it together: `QueryService` owns the stage
+                cache and hands it to the scheduler; `run(stream)` runs
+                the scheduler and reports throughput (qps), p50/p99
+                latency (with the queue-wait / in-lane breakdown), and
+                cache hit rate. With a tenant registry the
                 stats gain a per-tenant breakdown (SLO-miss rate,
                 rejected/degraded counts, partition cache counters).
 

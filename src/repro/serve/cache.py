@@ -1,7 +1,8 @@
 """Runtime stage-result cache: LRU under a byte budget + O(1) invalidation.
 
-Replaces the executor's original `db._stage_cache` dict, which dropped the
-ENTIRE cache whenever the byte budget overflowed. Entries are keyed by the
+One owner creates a cache and hands it down to every executor that may
+reuse its stages (`QueryService` -> `LaneScheduler` -> `AdaptiveRun` ->
+`Executor`; `train_agent` keeps one per call). Entries are keyed by the
 stage's structural signature — which, since the signatures embed each base
 table's version tag (see `Database.table_version`), makes invalidation O(1)
 on update: bumping a table's version means every signature derived from the
@@ -92,8 +93,9 @@ class StageCache:
         return True
 
     def note_invalidation(self, table: str) -> None:
-        """Called (via `Database.bump_version`) when a table mutates. O(1):
-        the version tag inside every signature does the actual fencing."""
+        """Called by the cache's owner when it applies a delta to a table
+        (`serve.deltas.apply_delta`). O(1): the version tag inside every
+        signature does the actual fencing."""
         self.stats.invalidations += 1
 
     def clear(self) -> None:
@@ -113,18 +115,16 @@ class PartitionedStageCache(StageCache):
 
     Each tenant evicts ONLY against its own byte budget, so a
     noisy-neighbor tenant flooding the cache can never push out a
-    well-behaved tenant's entries — the isolation property
-    `benchmarks/bench_qos.py` pins down. Invalidation stays O(1) and
-    GLOBAL: signatures embed per-table version tags, so one delta fences
-    every tenant's stale entries at once without scanning any partition
-    (`note_invalidation` only bumps the shared counter).
+    well-behaved tenant's entries (tests/test_qos.py pins this down).
+    Invalidation stays O(1) and GLOBAL: signatures embed per-table version
+    tags, so one delta fences every tenant's stale entries at once without
+    scanning any partition (`note_invalidation` only bumps the shared
+    counter).
 
     The object itself IS the default partition (a plain `StageCache`
-    with `default_bytes`), so code that treats `db._stage_cache` as a
-    flat cache — `sql.executor.Executor`'s auto-attach, direct
-    `run_adaptive` calls — keeps working unchanged and lands in the
-    default tenant's budget. The scheduler routes each lane to
-    `partition(arrival.tenant)` explicitly. Only tenants with a
+    with `default_bytes`), so an executor handed the whole object uses
+    it as a flat cache in the default tenant's budget. The scheduler
+    routes each lane to `partition(arrival.tenant)`. Only tenants with a
     CONFIGURED budget get their own partition; unknown tenant ids share
     the default one, so total cache memory stays bounded by
     sum(budgets) + default_bytes no matter how many distinct ids a

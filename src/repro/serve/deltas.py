@@ -41,8 +41,11 @@ class DeltaBatch:
                 f"-{self.delete_frac:.0%})")
 
 
-def apply_delta(db: Database, delta: DeltaBatch) -> Dict[str, int]:
-    """Mutate the table in place and bump its version. Returns counts."""
+def apply_delta(db: Database, delta: DeltaBatch,
+                cache=None) -> Dict[str, int]:
+    """Mutate the table in place and bump its version; `cache` (the stage
+    cache the caller owns over `db`, if any) counts the invalidation.
+    Returns counts."""
     t = db.table(delta.table)
     rng = np.random.default_rng(delta.seed)
     appended = deleted = 0
@@ -57,6 +60,8 @@ def apply_delta(db: Database, delta: DeltaBatch) -> Dict[str, int]:
         if deleted:
             t.columns = {k: v[keep] for k, v in t.columns.items()}
     db.bump_version(delta.table)
+    if cache is not None:
+        cache.note_invalidation(delta.table)
     return {"appended": appended, "deleted": deleted}
 
 

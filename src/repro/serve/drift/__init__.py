@@ -31,10 +31,8 @@ coverage. Four cooperating pieces:
                  (generation-fenced), installs re-covered probe sets.
 
 Everything decides from virtual-clock state, modeled costs and seeded
-RNGs, so serving with the control plane attached stays bit-reproducible;
-`benchmarks/bench_drift.py` sweeps refresh-policy x predictor-refresh
-arms under a drifting delta workload. See serve/README.md for the
-dataflow diagram.
+RNGs, so serving with the control plane attached stays bit-reproducible.
+See serve/README.md for the dataflow diagram.
 """
 from repro.serve.drift.controller import DriftController, DriftStats
 from repro.serve.drift.detector import DriftDetector, TableDrift

@@ -2,9 +2,7 @@
 
 The paper keeps statistics deliberately stale and defers correction to
 runtime; classical practice re-ANALYZEs on a cadence and trusts the
-optimizer. `RefreshPolicy` makes that a pluggable, benchmarked decision
-(`benchmarks/bench_drift.py` sweeps all four kinds against online
-adaptation):
+optimizer. `RefreshPolicy` makes that a pluggable decision:
 
   never      today's baseline: statistics are written once and never
              touched — the scheduler path is bit-identical to a run with

@@ -196,8 +196,7 @@ class Tracer:
                                            for l in s.lanes))
         m.gauge("queue_depth", fn=lambda s=scheduler: len(s._pending))
         m.gauge("cache_bytes",
-                fn=lambda s=scheduler: float(getattr(
-                    getattr(s.db, "_stage_cache", None), "bytes", 0) or 0))
+                fn=lambda s=scheduler: float(getattr(s.cache, "bytes", 0)))
         # give the policy store (if any hook installs one later) a path
         # back to this tracer: PolicyStore reads scheduler.obs lazily.
 

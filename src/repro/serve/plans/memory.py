@@ -4,8 +4,7 @@ A `PlanEntry` is the best-known re-optimization action sequence for one
 (template signature x table-version band): replaying its stored actions
 through a resumable `AdaptiveRun` reproduces the winning plan WITHOUT a
 single `act_batch` call — a memoized hit removes the query from every
-policy batch, which is the host-side win `benchmarks/bench_planmem.py`
-prices. The memory is fed from two sides:
+policy batch, a host-side win. The memory is fed from two sides:
 
   serve ingest   every non-memoized successful completion is a promotion
                  candidate: its action sequence replaces the incumbent

@@ -7,7 +7,7 @@ not-yet-optimized (template x band) keys — hottest first, as many as the
 round's `sim_budget` covers — running a deterministic beam search over
 action sequences per key, simulating each candidate prefix through a
 private resumable `AdaptiveRun` on the LIVE database
-(`reuse_stages=False`, so simulations never warm the serving cache or
+(`cache=None`, so simulations never warm the serving cache or
 touch the virtual clock; all search cost is measured host seconds).
 
 It also rides the delta barrier: when a delta moves templates onto a new
@@ -176,12 +176,12 @@ class Superoptimizer:
     def _simulate(self, q, prefix: Tuple[int, ...], space):
         """Run `q` with `prefix` applied at its first stage boundaries and
         noop thereafter; returns (modeled cost, mask after the prefix).
-        reuse_stages=False keeps the sim off the serving stage cache — no
+        cache=None keeps the sim off the serving stage cache — no
         serving-visible side effects, fully deterministic."""
         sched = self._sched
         run = AdaptiveRun(sched.db, q, syntactic_plan(q), sched.est,
                           sched.cluster, max_hook_steps=len(prefix) + 1,
-                          reuse_stages=False)
+                          cache=None)
         state = run.start()
         for a in prefix:
             if state is None:

@@ -35,7 +35,7 @@ n_lanes queries) are special cases of the same loop, and
 Scheduling still changes what matters for serving: under "lockstep" a
 wave's lanes all wait for the slowest member before the next wave is
 admitted, while "async" refills each lane the moment it frees — which is
-what `benchmarks/bench_serve.py` quantifies on straggler-heavy mixes.
+what tests/test_serve.py's straggler test pins down.
 """
 from __future__ import annotations
 
@@ -178,6 +178,10 @@ class LaneScheduler:
              suspended lanes). Affects host batching and tick ordering
              only — per-query plans, latencies and completion times are
              window-independent.
+    cache    the stage cache every run of this scheduler reads and fills
+             (a `serve.cache.StageCache`; a `PartitionedStageCache` is
+             routed per tenant). None reuses nothing. Deltas applied at
+             the write barrier count their invalidation on it.
     admission  optional `serve.qos.AdmissionPolicy`: overrides the pick
              among pending queries (EDF + fair share), and may defer
              (rate limits), degrade (shrunken hook budget) or reject
@@ -188,7 +192,7 @@ class LaneScheduler:
                  stage: int = 3, explore: bool = False,
                  cluster: Optional[ClusterModel] = None,
                  policy: str = "async", window: Optional[float] = None,
-                 reuse_stages: bool = True, admission=None, recovery=None,
+                 cache=None, admission=None, recovery=None,
                  plan_memory=None):
         assert policy in ("async", "edf", "lockstep"), policy
         assert admission is None or policy != "lockstep", \
@@ -200,7 +204,7 @@ class LaneScheduler:
         self.cluster = cluster if cluster is not None else ClusterModel()
         self.policy = policy
         self.window = None if policy == "lockstep" else window
-        self.reuse_stages = reuse_stages
+        self.cache = cache
         if admission is None and policy == "edf":
             # lazy: scheduler must stay importable without pulling the
             # whole qos package at module load
@@ -348,7 +352,7 @@ class LaneScheduler:
                 # window the task just charged
                 t_apply = max([item.t, self._write_ts] +
                               [l.free_at for l in self.lanes])
-                counts = apply_delta(self.db, item.delta)
+                counts = apply_delta(self.db, item.delta, self.cache)
                 self._write_ts = t_apply
                 self.delta_log.append((t_apply, item.delta, counts))
                 for cb in self.on_delta:
@@ -457,10 +461,9 @@ class LaneScheduler:
             else:
                 steps = self.agent.cfg.max_steps if hook_budget is None \
                     else min(hook_budget, self.agent.cfg.max_steps)
-            cache = None
-            shared = getattr(self.db, "_stage_cache", None)
-            if self.reuse_stages and isinstance(shared, PartitionedStageCache):
-                cache = shared.partition(arrival.tenant)
+            cache = self.cache.partition(arrival.tenant) \
+                if isinstance(self.cache, PartitionedStageCache) \
+                else self.cache
             plan = syntactic_plan(q) if ticket is None or ticket.plan is None \
                 else ticket.plan
             faults = None
@@ -473,8 +476,7 @@ class LaneScheduler:
                 else self.obs.on_admit(lane, arrival, admit_t)
             run = AdaptiveRun(self.db, q, plan, self.est,
                               self.cluster, max_hook_steps=steps,
-                              plan_time=0.0, reuse_stages=self.reuse_stages,
-                              cache=cache, faults=faults,
+                              plan_time=0.0, cache=cache, faults=faults,
                               init_mats=None if ticket is None
                               else ticket.mats,
                               init_stages_done=0 if ticket is None

@@ -1,13 +1,14 @@
 """Service façade: one object that owns the cache, the scheduler and the
 serving metrics.
 
-`QueryService` installs a fresh `StageCache` on the database (so every
-service instance starts with cold, independently-budgeted cache state),
-runs an arrival stream through a `LaneScheduler`, and distills the
-completions into the numbers a serving benchmark cares about: throughput
-(qps on the virtual clock), p50/p99 query latency (queueing + execution)
-with the queue-wait/in-lane breakdown, cache hit rate, and the host-side
-cost of the policy (decision batches per tick, hook seconds per query).
+`QueryService` creates a fresh `StageCache` (so every service instance
+starts with cold, independently-budgeted cache state) and hands it to
+every `LaneScheduler` it creates. It runs an arrival stream through one
+and distills the completions into the numbers a serving benchmark cares
+about: throughput (qps on the virtual clock), p50/p99 query latency
+(queueing + execution) with the queue-wait/in-lane breakdown, cache hit
+rate, and the host-side cost of the policy (decision batches per tick,
+hook seconds per query).
 
 With a `TenantRegistry` the cache becomes per-tenant partitions
 (`PartitionedStageCache`) and the stats gain a per-tenant breakdown —
@@ -126,7 +127,7 @@ class QueryService:
                  cluster: Optional[ClusterModel] = None, n_lanes: int = 8,
                  policy: str = "async", window: Optional[float] = None,
                  cache_bytes: int = 256 * 1024 * 1024,
-                 reuse_stages: bool = True, explore: bool = False,
+                 explore: bool = False,
                  hooks: Sequence = (), tenants=None, admission=None,
                  recovery=None, obs=None, monitor=None, plan_memory=None):
         """`hooks` are objects with an `attach(scheduler)` method (e.g. the
@@ -158,7 +159,6 @@ class QueryService:
         self.est = est if est is not None else Estimator(db, db.stats)
         self.cluster = cluster if cluster is not None else ClusterModel()
         self.n_lanes, self.policy, self.window = n_lanes, policy, window
-        self.reuse_stages = reuse_stages
         self.explore = explore
         self.hooks = list(hooks)
         self.tenants = tenants
@@ -170,21 +170,19 @@ class QueryService:
         self.obs = obs
         self.monitor = monitor
         self.plan_memory = plan_memory
-        if reuse_stages:
-            if tenants is not None:
-                # every REGISTERED tenant gets its own partition (explicit
-                # budget or the service default); unregistered ids share
-                # the default partition, so memory stays bounded
-                budgets = {t: tenants.spec(t).cache_bytes
-                           if tenants.spec(t).cache_bytes is not None
-                           else cache_bytes for t in tenants.tenants}
-                self.cache = PartitionedStageCache(
-                    default_bytes=cache_bytes, budgets=budgets)
-            else:
-                self.cache = StageCache(max_bytes=cache_bytes)
-            db._stage_cache = self.cache     # shared by every AdaptiveRun
+        # the service owns the stage cache: every scheduler it creates
+        # gets this one object, so stages are reused across run() calls
+        if tenants is not None:
+            # every REGISTERED tenant gets its own partition (explicit
+            # budget or the service default); unregistered ids share the
+            # default partition, so memory stays bounded
+            budgets = {t: tenants.spec(t).cache_bytes
+                       if tenants.spec(t).cache_bytes is not None
+                       else cache_bytes for t in tenants.tenants}
+            self.cache = PartitionedStageCache(
+                default_bytes=cache_bytes, budgets=budgets)
         else:
-            self.cache = None
+            self.cache = StageCache(max_bytes=cache_bytes)
         self.scheduler: Optional[LaneScheduler] = None
 
     def run(self, stream: Sequence[Arrival]) \
@@ -199,8 +197,8 @@ class QueryService:
                 self.db, self.est, self.agent, n_lanes=self.n_lanes,
                 explore=self.explore, cluster=self.cluster,
                 policy=self.policy, window=self.window,
-                reuse_stages=self.reuse_stages,
-                admission=self.admission, recovery=self.recovery)
+                cache=self.cache, admission=self.admission,
+                recovery=self.recovery)
             if self.obs is not None:
                 self.obs.attach(self.scheduler)
             if self.plan_memory is not None:
@@ -225,10 +223,9 @@ class QueryService:
         `clear_entries=True` the cache contents are dropped too, so the
         next run starts cold — on an unmutated database that makes two
         identical streams produce identical stats end to end."""
-        if self.cache is not None:
-            self.cache.reset_stats()
-            if clear_entries:
-                self.cache.clear()
+        self.cache.reset_stats()
+        if clear_entries:
+            self.cache.clear()
         pred = getattr(self.admission, "predictor", None)
         if pred is not None and hasattr(pred, "reset_stats"):
             pred.reset_stats()
@@ -255,9 +252,7 @@ class QueryService:
                          for q, s in zip(queries, seeds)])
 
     # -------------------------------------------------------------- stats
-    def _cache_dict(self) -> Optional[Dict[str, float]]:
-        if self.cache is None:
-            return None
+    def _cache_dict(self) -> Dict[str, float]:
         if isinstance(self.cache, PartitionedStageCache):
             return self.cache.aggregate_stats()
         return self.cache.stats.as_dict()
@@ -300,8 +295,6 @@ class QueryService:
     def _stats(self, comps: List[Completion]) -> ServiceStats:
         sched = self.scheduler
         rejects = sched.rejections
-        # NB: `if self.cache` would be False for an EMPTY cache (StageCache
-        # defines __len__) — the None-check matters on the empty-stream path
         if not comps:
             return ServiceStats(
                 0, 0, 0.0, 0.0, 0.0, 0.0, 0.0, 0.0, self._cache_dict(),

@@ -77,12 +77,8 @@ class Database:
         return self.versions.get(name, 0)
 
     def bump_version(self, name: str) -> int:
-        """Record that `name`'s data changed; notifies an attached stage
-        cache (if any) so invalidations are observable in its counters."""
+        """Record that `name`'s data changed."""
         self.versions[name] = self.versions.get(name, 0) + 1
-        cache = getattr(self, "_stage_cache", None)
-        if cache is not None and hasattr(cache, "note_invalidation"):
-            cache.note_invalidation(name)
         return self.versions[name]
 
 

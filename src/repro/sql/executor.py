@@ -23,7 +23,6 @@ from typing import Callable, Dict, Generator, List, Optional, Tuple
 
 import numpy as np
 
-from repro.serve.cache import StageCache
 from repro.spans import span
 from repro.sql.catalog import Database
 from repro.sql.cbo import Estimator
@@ -209,32 +208,17 @@ class Executor:
     signature names them, so two queries that share a join's inputs but
     read different columns later never share its entry.
 
-    The cache itself is a `serve.cache.StageCache` shared via the database
-    object: LRU eviction under a byte budget, and every signature embeds
-    the base tables' version tags, so delta-table updates invalidate
-    derived entries in O(1)."""
-
-    _CACHE_MAX_BYTES = 256 * 1024 * 1024   # default budget for auto-created
-    _ENTRY_MAX_BYTES = 32 * 1024 * 1024    #   caches; huge stages not pinned
+    The cache is handed in by whoever owns it (`serve.cache.StageCache`
+    or anything with its `get` / `put` / `stats`): LRU eviction under a
+    byte budget, and every signature embeds the base tables' version
+    tags, so delta-table updates invalidate derived entries in O(1).
+    `cache=None` reuses nothing."""
 
     def __init__(self, db: Database, cluster: Optional[ClusterModel] = None,
-                 reuse_stages: bool = True,
-                 cache: Optional[StageCache] = None):
+                 cache=None):
         self.db = db
         self.cluster = cluster if cluster is not None else ClusterModel()
-        if not reuse_stages:
-            self._cache = None
-        elif cache is not None:
-            # explicit cache (e.g. one tenant's partition of a
-            # serve.cache.PartitionedStageCache, routed by the scheduler)
-            self._cache = cache
-        else:
-            cache = getattr(db, "_stage_cache", None)
-            if not isinstance(cache, StageCache):
-                cache = StageCache(self._CACHE_MAX_BYTES,
-                                   self._ENTRY_MAX_BYTES)
-                db._stage_cache = cache
-            self._cache = cache
+        self._cache = cache
         self.probe: Optional[str] = None   # last join's probe path, None
         #   when the stage cache served it (see `_join_indices`)
         self.cols: Optional[int] = None    # last join's output columns and
@@ -244,7 +228,7 @@ class Executor:
     @property
     def cache_stats(self):
         """hit/miss/evict/invalidate counters of the attached stage cache
-        (`serve.cache.CacheStats`), or None when reuse is off."""
+        (`serve.cache.CacheStats`), or None without a cache."""
         return None if self._cache is None else self._cache.stats
 
     # -------------------------------------------------- base scan
@@ -478,13 +462,14 @@ class AdaptiveRun:
                  max_hook_steps: int = 3,
                  plan_time: float = 0.0,
                  aqe_switching: bool = True,
-                 reuse_stages: bool = True,
-                 cache: Optional[StageCache] = None,
+                 cache=None,
                  faults=None,
                  init_mats: Optional[Dict[frozenset, MaterializedRel]] = None,
                  init_stages_done: int = 0,
                  trace=None):
-        """`faults` is an optional per-run fault profile (an object with
+        """`cache` is the stage cache the run reads and fills (its owner's,
+        e.g. the scheduler's or one tenant's partition); None reuses
+        nothing. `faults` is an optional per-run fault profile (an object with
         `charge(seconds, state) -> seconds` that may raise `QueryFailure`,
         see serve.recover.faults) consulted at every latency charge; None
         keeps the execution path bit-identical. `init_mats` /
@@ -506,8 +491,7 @@ class AdaptiveRun:
         self._faults = faults
         self._trace = trace
         self.result: Optional[RunResult] = None
-        self._ex = Executor(db, self.cluster, reuse_stages=reuse_stages,
-                            cache=cache)
+        self._ex = Executor(db, self.cluster, cache=cache)
         self._stages: List[StageRecord] = []
         self._tot_shuffles = 0
         self._tot_sbytes = 0.0
@@ -694,18 +678,19 @@ def run_adaptive(db: Database, query: Query, plan: Node, est: Estimator,
                  max_hook_steps: int = 3,
                  plan_time: float = 0.0,
                  aqe_switching: bool = True,
-                 reuse_stages: bool = True) -> RunResult:
+                 cache=None) -> RunResult:
     """Execute `plan` stage-by-stage with AQE + optional extension hook.
 
     The hook is invoked at stage boundaries (including once pre-execution,
     matching AQORA's two-phase optimization) at most `max_hook_steps` times;
     it may return a REPLACEMENT remaining plan (built from the same leaves).
-    Implemented by driving an `AdaptiveRun` to completion.
+    `cache` is the caller's stage cache (None: no reuse). Implemented by
+    driving an `AdaptiveRun` to completion.
     """
     run = AdaptiveRun(db, query, plan, est, cluster,
                       max_hook_steps=max_hook_steps if hook is not None else 0,
                       plan_time=plan_time, aqe_switching=aqe_switching,
-                      reuse_stages=reuse_stages)
+                      cache=cache)
     st = run.start()
     while st is not None:
         st = run.resume(hook(st))

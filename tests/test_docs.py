@@ -9,13 +9,13 @@ ROOT = pathlib.Path(__file__).resolve().parents[1]
 def test_deliverable_files_exist():
     for p in ("README.md", "DESIGN.md", "EXPERIMENTS.md", "pyproject.toml",
               "src/repro/launch/mesh.py", "src/repro/launch/dryrun.py",
-              "benchmarks/run.py", "examples/quickstart.py"):
+              "bench/run.py", "examples/quickstart.py"):
         assert (ROOT / p).exists(), p
 
 
 def test_design_experiment_index_targets_exist():
     text = (ROOT / "DESIGN.md").read_text()
-    refs = re.findall(r"`(benchmarks/[\w/.]+?\.py)", text)
+    refs = re.findall(r"`(bench/[\w/.]+?\.(?:py|json))`", text)
     assert refs, "DESIGN.md must index benchmark modules"
     for r in set(refs):
         assert (ROOT / r).exists(), f"DESIGN.md references missing {r}"

@@ -14,6 +14,7 @@ from scenarios import (drifting_delta_stream, fast_query, fresh_db,
                        make_agent, mi_join_query, straggler_query)
 
 from repro.learn import PolicyStore, ReplayBuffer, TrajectoryHarvester
+from repro.serve.cache import StageCache
 from repro.serve.deltas import DeltaBatch, apply_delta
 from repro.serve.drift import (CoverageProbeSet, DriftController,
                                DriftDetector, RefreshPolicy, TableDrift)
@@ -306,21 +307,22 @@ def test_reanalyze_leaves_stage_cache_correct(job_workload, agent):
     db = fresh_db(scale=0.06)
     est = Estimator(db, db.stats)
     q = mi_join_query("q_reanalyze")
-    r1 = run_adaptive(db, q, syntactic_plan(q), est)
-    n_entries = len(db._stage_cache)
+    cache = StageCache()
+    r1 = run_adaptive(db, q, syntactic_plan(q), est, cache=cache)
+    n_entries = len(cache)
     assert n_entries > 0
     # incremental re-ANALYZE of every table the query touches
     for t in ("title", "movie_info", "info_type"):
         db.stats.tables[t] = analyze_table(db, t,
                                            rng=np.random.default_rng(4))
-    assert len(db._stage_cache) == n_entries     # nothing was dropped
-    assert db._stage_cache.stats.invalidations == 0
-    r2 = run_adaptive(db, q, syntactic_plan(q), est)        # warm
-    ref = run_adaptive(db, q, syntactic_plan(q), est, reuse_stages=False)
+    assert len(cache) == n_entries               # nothing was dropped
+    assert cache.stats.invalidations == 0
+    r2 = run_adaptive(db, q, syntactic_plan(q), est, cache=cache)   # warm
+    ref = run_adaptive(db, q, syntactic_plan(q), est)       # no cache
     assert r2.latency == ref.latency == r1.latency
     assert [s.out_rows for s in r2.stages] == \
         [s.out_rows for s in ref.stages]
-    assert db._stage_cache.stats.hits > 0        # the entries were reused
+    assert cache.stats.hits > 0                  # the entries were reused
 
 
 def test_reanalyze_fences_policy_store_incumbent_cache(job_workload, agent,

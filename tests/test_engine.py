@@ -267,7 +267,7 @@ def test_join_probe_changes_no_run(job_db, estimator, job_workload,
 
     def runs():
         return [run_adaptive(job_db, q, syntactic_plan(q), estimator,
-                             ClusterModel(), reuse_stages=False)
+                             ClusterModel())
                 for q in queries]
 
     seen = []
@@ -296,7 +296,7 @@ def test_join_probe_is_named_on_cache_misses_only():
     when the stage cache served the join."""
     db = _tiny_db(3)
     q = _tiny_query()
-    ex = Executor(db)
+    ex = Executor(db, cache=StageCache())
     a, _ = ex.scan(q, "a")
     b, _ = ex.scan(q, "b")
     first, _ = ex.join(q, b, a, q.conds[:1], SMJ)     # b.a_id = a.id
@@ -408,7 +408,7 @@ def _rewriting_hook(queries):
 def _serve_pool(db, est, queries, hook, passes=2):
     """Every query run `passes` times on one shared stage cache, so the
     later passes are served from it; the runs' results in order."""
-    cache = StageCache(Executor._CACHE_MAX_BYTES, Executor._ENTRY_MAX_BYTES)
+    cache = StageCache()
     out = []
     for _ in range(passes):
         for q in queries:
@@ -496,7 +496,7 @@ def test_stage_cache_keeps_apart_joins_that_feed_other_columns(job_db,
     n = lambda name: np.bincount(job_db.table(name).columns["movie_id"],
                                  minlength=t.nrows)
     expected = int((n("movie_companies") * n("movie_info"))[ids].sum())
-    cache = StageCache(Executor._CACHE_MAX_BYTES, Executor._ENTRY_MAX_BYTES)
+    cache = StageCache()
     probes = []
     join = Executor.join
 
@@ -580,3 +580,26 @@ def test_dynamic_snapshot_filters_years():
     old = datagen.make_job_like(scale=0.1, seed=0, year_max=1950)
     assert 0 < old.tables["title"].nrows < 0.6 * full.tables["title"].nrows
     assert old.tables["cast_info"].nrows < full.tables["cast_info"].nrows
+
+
+def test_sql_imports_nothing_above_it():
+    """The engine stands alone: no module under `repro/sql` imports the
+    serving, decision-loop or learning packages (a stage cache and every
+    other collaborator is handed in by its owner)."""
+    import ast
+    import pathlib
+    above = ("repro.serve", "repro.core", "repro.learn")
+    root = pathlib.Path(executor.__file__).parent
+    found = []
+    for path in sorted(root.rglob("*.py")):
+        for node in ast.walk(ast.parse(path.read_text())):
+            if isinstance(node, ast.Import):
+                names = [a.name for a in node.names]
+            elif isinstance(node, ast.ImportFrom) and node.level == 0:
+                names = [node.module or ""]
+            else:
+                continue
+            found += [(path.name, n) for n in names
+                      if n == "repro" or n.startswith(above)]
+    assert len(list(root.rglob("*.py"))) > 5
+    assert found == []

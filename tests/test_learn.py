@@ -206,6 +206,38 @@ def test_online_serving_bit_reproducible_with_learner_on(job_workload,
         [g["accepted"] for g in l2.store.gate_log]
 
 
+def test_shadow_learning_serves_bit_identically_to_frozen(job_workload,
+                                                         tmp_path):
+    """The whole loop running with its store in shadow mode (harvest,
+    updates, probe gates on the service's stage cache) changes no
+    completion: it serves exactly what the frozen policy serves."""
+    qs = job_workload.train[:6]
+    rng = np.random.default_rng(9)
+    stream = [Arrival(0.5 * i, query=qs[i % len(qs)],
+                      seed=int(rng.integers(2 ** 31))) for i in range(12)]
+
+    def serve(hooks):
+        db = fresh_db(scale=0.05)
+        svc = QueryService(db, agent, est=Estimator(db, db.stats),
+                           n_lanes=2, hooks=hooks)
+        comps, _ = svc.run(stream)
+        return comps, svc
+
+    agent = make_agent(job_workload, seed=0)
+    frozen, _ = serve([])
+    store = PolicyStore(tmp_path / "ps", job_workload.test[:2],
+                        mode="shadow")
+    h, l = make_online_loop(agent, store=store, update_every=3,
+                            sample_size=3, gate_every=2, seed=5)
+    shadow, svc = serve([h, l])
+    assert [c.traj.actions for c in shadow] == \
+        [c.traj.actions for c in frozen]
+    assert [c.finish_t for c in shadow] == [c.finish_t for c in frozen]
+    assert l.stats.updates > 0 and store.gate_log
+    assert not any(g["swapped"] for g in store.gate_log)
+    assert store.cache is svc.cache            # probes reuse its stages
+
+
 # ------------------------------------------------------------- the gate
 def _nan_corrupt(agent):
     agent.actor = jax.tree_util.tree_map(lambda x: x * np.nan, agent.actor)

@@ -178,7 +178,7 @@ def _replan_agent():
 
 
 def _drift_world():
-    """bench_drift's stale-stats shape: movie_info shrunk young, the
+    """The drift scenario's stale-stats shape: movie_info shrunk young, the
     catalog ANALYZEd post-shrink — in sync until the growth delta lands,
     after which every trap OOMs under the cap until a re-ANALYZE."""
     from repro.sql.catalog import analyze
@@ -391,3 +391,225 @@ def test_alert_driven_reanalyze_heals_the_drift_outage():
     t_fix = next(t for t, lbl in svc.scheduler.task_log
                  if lbl.startswith("re-analyze[alert]:"))
     assert fails([c for c in wired if c.admit_t > t_fix]) == 0
+
+
+# ------------------------------------------ refresh policies vs the drift
+def _churned_drift_stream(db):
+    """`_drift_stream` with a movie_keyword churn delta after every sixth
+    post-drift query: tables a refresh policy may or may not pay for."""
+    mk = db.table("movie_keyword").nrows
+    out, n, drifted = [], 0, False
+    for a in _drift_stream(db):
+        out.append(a)
+        if a.delta is not None:
+            drifted = True
+        elif drifted:
+            n += 1
+            if n % 6 == 0:
+                out.append(Arrival(a.t, delta=DeltaBatch(
+                    "movie_keyword", n_append=mk // 50, delete_frac=0.02,
+                    seed=1000 + n)))
+    return out
+
+
+def test_refresh_policies_unarm_the_drift_trap_within_budget():
+    """Under "never" the stale catalog keeps walking into the trap after
+    movie_info grows; "always" and "threshold" refresh at the barrier, so
+    no trap fails and the post-drift tail collapses; "budgeted" does the
+    same while paying for the one big refresh and not for the churn."""
+    from repro.serve.drift import DriftController, RefreshPolicy
+    db, _ = _drift_world()
+    big = _TRAP_CLUSTER.scan_time(
+        (1 + _GROWTH_X) * db.table("movie_info").bytes() * 0.05) + \
+        _TRAP_CLUSTER.stage_overhead
+    policies = {
+        "never": RefreshPolicy("never"),
+        "always": RefreshPolicy("always"),
+        "threshold": RefreshPolicy("threshold", threshold=1.0),
+        "budgeted": RefreshPolicy("budgeted", threshold=0.25,
+                                  budget_s=1.5 * big)}
+    arms = {}
+    for name, policy in policies.items():
+        db, est = _drift_world()
+        stream = _churned_drift_stream(db)
+        ctl = DriftController(policy=policy)
+        svc = QueryService(db, _replan_agent(), est=est, n_lanes=2,
+                           cluster=_TRAP_CLUSTER, hooks=[ctl])
+        comps, _ = svc.run(stream)
+        t_drift = next(a.t for a in stream if a.delta is not None)
+        post = [c.latency for c in comps if c.arrival_t > t_drift]
+        arms[name] = (sum(c.result.failed for c in comps),
+                      float(np.percentile(post, 99)),
+                      ctl.stats.analyze_modeled_s)
+    fails, p99, _ = arms["never"]
+    assert fails > 0                           # the trap is armed
+    for name in ("always", "threshold", "budgeted"):
+        assert arms[name][0] == 0 and arms[name][1] < p99 / 5
+    assert 0 < arms["budgeted"][2] < arms["always"][2]
+
+
+# ----------------------------------------- seeded incidents: detect + RCA
+_SEEDED_CFG = dict(window=12, min_warm=6, min_n=8, cooldown=6, merge_gap=10,
+                   lookback=16, baseline_max=96)
+_LAG_BOUND = 24                # completions from injection to detection
+
+
+def _sig(comps):
+    return [(c.seq, c.finish_t, c.latency, c.result.failed, c.failure_kind,
+             c.attempts) for c in comps]
+
+
+def _monitor(monitored, **kw):
+    return SloMonitor(config=MonitorConfig(**_SEEDED_CFG), **kw) \
+        if monitored else None
+
+
+def _scn_fault_burst(monitored, tmp_path):
+    """Stats in sync (no drift), an injector confined to stream positions
+    28-43; the retry ladder absorbs most of the burst."""
+    from repro.serve.recover import (FaultInjector, RecoveryManager,
+                                     RetryPolicy)
+    db, est = _drift_world()
+    traps, fasts = _drift_queries()
+    rng = np.random.default_rng(31)
+    t, stream = 0.0, []
+    for i in range(64):
+        t += float(rng.exponential(1.0))
+        q = traps[(i // 5) % 3] if i % 5 == 0 else fasts[i % 5]
+        stream.append(Arrival(t, query=q, seed=int(rng.integers(2 ** 31)),
+                              deadline=t + 30.0))
+    injector = FaultInjector(seed=23, p_crash=0.02, p_transient=0.3,
+                             p_slow=0.1, slow_factor=(8.0, 48.0),
+                             window=(28, 44))
+    mgr = RecoveryManager(injector=injector,
+                          retry=RetryPolicy(max_attempts=3, backoff=0.5))
+    mon = _monitor(monitored)
+    svc = QueryService(db, _replan_agent(), est=est, n_lanes=4,
+                       cluster=_TRAP_CLUSTER, recovery=mgr, monitor=mon)
+    comps, _ = svc.run(stream)
+    return comps, mon, stream[28].t
+
+
+def _scn_hot_tenant(monitored, tmp_path):
+    """Two lanes: tenant a at a steady 0.8 q/s, tenant b at 0.1 q/s until
+    a flood of 24 arrivals at 6 q/s from t=55 backs the queue up."""
+    from repro.baselines import CboReplanAgent
+    db, est = _drift_world()
+    fast = [fast_query(i) for i in range(8)]
+    rng = np.random.default_rng(17)
+
+    def arrival(t, q, tenant):
+        return Arrival(t, query=q, seed=int(rng.integers(2 ** 31)),
+                       deadline=t + 30.0, tenant=tenant)
+
+    stream, t, i = [], 0.0, 0
+    while t < 90.0:
+        t += float(rng.exponential(1.0 / 0.8))
+        stream.append(arrival(t, fast[i % 8], "a"))
+        i += 1
+    t = rng.exponential(1.0 / 0.1)
+    while t < 55.0:
+        stream.append(arrival(t, fast[(i + 3) % 8], "b"))
+        i += 1
+        t += float(rng.exponential(1.0 / 0.1))
+    t = 55.0
+    for j in range(24):
+        t += float(rng.exponential(1.0 / 6.0))
+        stream.append(arrival(t, fast[j % 8], "b"))
+    stream.sort(key=lambda a: a.t)
+    wl = Workload(name="flood", max_tables=3, train=fast, test=[])
+    mon = _monitor(monitored)
+    svc = QueryService(db, CboReplanAgent(WorkloadMeta.from_workload(wl),
+                                          max_steps=3),
+                       est=est, n_lanes=2, cluster=ClusterModel(
+                           timeout=60.0, oom_charge="detect",
+                           oom_spill_penalty=10.0), monitor=mon)
+    comps, _ = svc.run(stream)
+    return comps, mon, 55.0
+
+
+def _pin_head(agent, idx):
+    """Pin the actor head to one action: argmax then picks `idx` wherever
+    it is legal."""
+    import jax.numpy as jnp
+    head = dict(agent.actor["head"])
+    head["w2"] = jnp.zeros_like(head["w2"])
+    b2 = np.zeros(head["b2"].shape, np.float32)
+    b2[idx] = 50.0
+    head["b2"] = jnp.asarray(b2)
+    agent.actor = {**agent.actor, "head": head}
+
+
+def _scn_policy_swap(monitored, tmp_path):
+    """An incumbent pinned to cbo-replan (traps re-planned on fresh stats)
+    is hot-swapped after completion 23 for one pinned to noop (the traps'
+    fact-fact join blows the cap)."""
+    from repro.core.agent import AgentConfig, AqoraAgent
+    from repro.learn.policy_store import PolicyStore
+    from repro.serve.deltas import apply_delta
+    from repro.serve.scheduler import LaneScheduler
+    from repro.sql.catalog import analyze
+    db = fresh_db(scale=0.06)
+    apply_delta(db, DeltaBatch("cast_info", n_append=120_000, seed=999))
+    db.stats = analyze(db, rng=np.random.default_rng(0))
+    traps = [trap_query(i, 1896 + i) for i in range(5)]
+    fast = [fast_query(i) for i in range(6)]
+    wl = Workload(name="swap", max_tables=3, train=traps + fast, test=[])
+    agent = AqoraAgent(WorkloadMeta.from_workload(wl),
+                       AgentConfig(max_steps=1), seed=0)
+    _pin_head(agent, 0)                        # action 0 == cbo(1)
+    store = PolicyStore(tmp_path / f"ps_{monitored}", probe=[], mode="gate")
+    store.commit(agent, 1)
+    sched = LaneScheduler(db, Estimator(db, db.stats), agent, n_lanes=4,
+                          cluster=ClusterModel(
+                              timeout=60.0, oom_charge="detect",
+                              oom_spill_penalty=10.0,
+                              materialize_cap=400_000))
+    mon = _monitor(monitored, store=store)
+    if monitored:
+        tracer = Tracer()
+        tracer.attach(sched)
+        store.obs = tracer                     # commits land in the log
+        mon.attach(sched)
+    rng = np.random.default_rng(41)
+    t, stream = 0.0, []
+    for i in range(60):
+        t += float(rng.exponential(0.5))
+        q = traps[(i // 2) % 5] if i % 2 == 0 else fast[i % 6]
+        stream.append(Arrival(t, query=q, seed=int(rng.integers(2 ** 31)),
+                              deadline=t + 30.0))
+
+    def swapper(comp):
+        if comp.seq == 23 and store.serving_step == 1:
+            _pin_head(agent, agent.space.noop_idx)
+            store.commit(agent, 2)
+    sched.on_complete.insert(0, swapper)
+    comps = sched.run(stream)
+    if monitored:
+        mon.finalize()
+    return comps, mon, next(c.finish_t for c in comps if c.seq == 23)
+
+
+_SCENARIOS = {"policy_swap": _scn_policy_swap,
+              "fault_burst": _scn_fault_burst,
+              "hot_tenant": _scn_hot_tenant}
+
+
+@pytest.mark.parametrize("cause", sorted(_SCENARIOS))
+def test_monitor_detects_and_attributes_seeded_incident(cause, tmp_path):
+    """A seeded incident is detected within a bounded number of
+    completions after its injection, its incident's top cause is the
+    injected one, and the watched run serves what an unwatched run
+    serves."""
+    import bisect
+    comps, mon, inject_t = _SCENARIOS[cause](True, tmp_path)
+    hit = next(((inc, a) for inc in mon.incidents for a in inc.anomalies
+                if a.t >= inject_t - 1e-9), None)
+    assert hit is not None, "the injected incident must be detected"
+    inc, a = hit
+    recs = [r["t"] for r in mon.records]
+    lag = bisect.bisect_right(recs, a.t) - bisect.bisect_left(recs, inject_t)
+    assert 0 <= lag <= _LAG_BOUND
+    assert inc.top is not None and inc.top.cause == cause
+    off, _, _ = _SCENARIOS[cause](False, tmp_path)
+    assert _sig(off) == _sig(comps)

@@ -210,6 +210,34 @@ def test_superoptimizer_promotes_deterministically():
     assert mem1.stats()["promoted_superopt"] == so1.stats.promotions
 
 
+def test_superoptimizer_simulations_leave_serving_cache_alone():
+    """Simulations run without the scheduler's stage cache: its counters,
+    entries and bytes are the same after every simulation as before."""
+    from repro.serve.cache import StageCache
+    q = trap_query(0, 1980)
+    cache = StageCache()
+    mem = PlanMemory()
+    so = Superoptimizer(mem, opt_every=2, sim_budget=16)
+    sched = _sched(fresh_db(), noop_agent_for(q, max_steps=2), n_lanes=1,
+                   plan_memory=mem, cache=cache)
+    so.attach(sched)
+    simulate, same = so._simulate, []
+
+    def state():
+        return cache.stats.as_dict(), len(cache), cache.bytes
+
+    def spy(*args):
+        before = state()
+        out = simulate(*args)
+        same.append(state() == before)
+        return out
+
+    so._simulate = spy
+    sched.run([Arrival(0.2 * i, query=q, seed=i) for i in range(4)])
+    assert so.stats.sims == len(same) > 0 and all(same)
+    assert cache.stats.misses > 0           # the served runs did use it
+
+
 def test_superoptimizer_never_regresses_incumbent():
     q = trap_query(0, 1980)
     agent = noop_agent_for(q, max_steps=2)

@@ -8,6 +8,7 @@ Scenario builders (fresh dbs, fast/straggler queries, the two-tenant QoS
 setup + stream, the FixedPredictor stub) live in tests/scenarios.py; the
 `agent` fixture is the session-scoped one from conftest.py.
 """
+import numpy as np
 import pytest
 
 from scenarios import (FixedPredictor, fast_query, fast_subset, fresh_db,
@@ -136,6 +137,31 @@ def test_qos_same_seed_identical_admissions(job_workload, agent):
     assert adm_stats["deferred"] > 0           # bulk hit its rate limit
 
 
+def test_qos_beats_plain_async_under_overload(job_workload, agent):
+    """On one lane the monster straggler overloads plain async (most
+    deadlines missed); EDF + QoS admission rejects it, misses fewer
+    deadlines, completes more queries on time, and no tenant's
+    non-degraded median latency pays for the control plane."""
+    def serve(**kw):
+        db = fresh_db()
+        svc = QueryService(db, agent, est=Estimator(db, db.stats),
+                           n_lanes=1, **kw)
+        stream = qos_stream(job_workload)
+        comps, stats = svc.run(stream)
+        on_time = sum(not c.result.failed and not c.slo_miss for c in comps)
+        p50 = {t: float(np.median([c.latency for c in comps
+                                   if c.tenant == t and not c.degraded]))
+               for t in ("gold", "bulk")}
+        return stats.slo_miss_rate, on_time / len(stream), p50
+
+    miss_a, good_a, p50_a = serve(policy="async")
+    reg, adm = qos_setup()
+    miss_q, good_q, p50_q = serve(policy="edf", tenants=reg, admission=adm)
+    assert miss_a >= 0.25                      # async is overloaded
+    assert miss_q < miss_a and good_q > good_a
+    assert all(p50_q[t] <= 1.05 * p50_a[t] for t in p50_q)
+
+
 def test_qos_admission_reusable_across_runs(job_workload, agent):
     """One admission object serving two streams: the second run must not
     inherit the first run's token-bucket end time (prepare resets the
@@ -203,7 +229,6 @@ def test_partition_invalidation_is_shared(job_workload, agent):
     db = fresh_db()
     est = Estimator(db, db.stats)
     cache = PartitionedStageCache(default_bytes=32 << 20)
-    db._stage_cache = cache
     q = fast_query(1)
     rows = {}
     for tenant in ("a", "b"):
@@ -212,9 +237,9 @@ def test_partition_invalidation_is_shared(job_workload, agent):
         assert run.start() is None
         rows[tenant] = [s.out_rows for s in run.result.stages]
     assert rows["a"] == rows["b"]
-    apply_delta(db, DeltaBatch("title", n_append=1000, seed=9))
+    apply_delta(db, DeltaBatch("title", n_append=1000, seed=9), cache)
     assert cache.stats.invalidations == 1      # one shared O(1) counter
-    ref = run_adaptive(db, q, syntactic_plan(q), est, reuse_stages=False)
+    ref = run_adaptive(db, q, syntactic_plan(q), est)       # no cache
     for tenant in ("a", "b"):
         run = AdaptiveRun(db, q, syntactic_plan(q), est, max_hook_steps=0,
                           cache=cache.partition(tenant))

@@ -93,28 +93,60 @@ def test_executor_exposes_cache_stats_and_hits(job_workload):
     db = fresh_db(scale=0.05)
     est = Estimator(db, db.stats)
     q = job_workload.test[0]
-    ex = Executor(db)
-    assert ex.cache_stats is not None and ex.cache_stats.hits == 0
-    r1 = run_adaptive(db, q, syntactic_plan(q), est)
+    cache = StageCache()
+    ex = Executor(db, cache=cache)
+    assert ex.cache_stats is cache.stats and ex.cache_stats.hits == 0
+    r1 = run_adaptive(db, q, syntactic_plan(q), est, cache=cache)
     misses_after_first = ex.cache_stats.misses
-    r2 = run_adaptive(db, q, syntactic_plan(q), est)
+    r2 = run_adaptive(db, q, syntactic_plan(q), est, cache=cache)
     assert ex.cache_stats.hits > 0, "replaying a query must hit the cache"
     assert ex.cache_stats.misses == misses_after_first
     assert r1.latency == r2.latency
     assert [s.out_rows for s in r1.stages] == [s.out_rows for s in r2.stages]
-    assert Executor(db, reuse_stages=False).cache_stats is None
+    assert Executor(db).cache_stats is None
 
 
 def test_executor_eviction_under_tiny_budget(job_workload):
     db = fresh_db(scale=0.05)
-    db._stage_cache = StageCache(max_bytes=64 * 1024)
+    cache = StageCache(max_bytes=64 * 1024)
     est = Estimator(db, db.stats)
     for q in job_workload.test[:6]:
-        run_adaptive(db, q, syntactic_plan(q), est)
-    st = db._stage_cache.stats
+        run_adaptive(db, q, syntactic_plan(q), est, cache=cache)
+    st = cache.stats
     assert st.evictions > 0, "tiny budget must evict"
-    assert len(db._stage_cache) > 0, "eviction is LRU, not clear-all"
-    assert db._stage_cache.bytes <= 64 * 1024
+    assert len(cache) > 0, "eviction is LRU, not clear-all"
+    assert cache.bytes <= 64 * 1024
+
+
+def test_runs_without_a_cache_leave_none_and_reuse_nothing(monkeypatch):
+    """An executor or `run_adaptive` call given no cache attaches none to
+    the database and computes every scan and join again on every run."""
+    db = fresh_db(scale=0.05)
+    est = Estimator(db, db.stats)
+    attrs = dict(vars(db))
+    q = mi_join_query("q_no_cache")
+    probes = []
+    join = Executor.join
+
+    def spy(self, *args):
+        out = join(self, *args)
+        probes.append(self.probe)
+        return out
+
+    monkeypatch.setattr(Executor, "join", spy)
+    runs = [run_adaptive(db, q, syntactic_plan(q), est) for _ in range(2)]
+    assert runs[0].stages == runs[1].stages
+    # a join served from a cache records no probe: every one was computed
+    assert len(probes) == 2 * len(runs[0].stages) > 0
+    assert all(p is not None for p in probes)
+    ex = Executor(db)
+    assert ex.cache_stats is None
+    a, _ = ex.scan(q, "mi")
+    b, _ = Executor(db).scan(q, "mi")
+    assert a.columns.keys() == b.columns.keys()
+    assert all(a.columns[k] is not b.columns[k] for k in a.columns)
+    assert vars(db).keys() == attrs.keys()
+    assert not any(isinstance(v, StageCache) for v in vars(db).values())
 
 
 # ----------------------------------------------------- delta invalidation
@@ -122,19 +154,23 @@ def test_invalidation_recomputes_bit_for_bit_vs_cache_off():
     db = fresh_db(scale=0.08)
     est = Estimator(db, db.stats)
     q = mi_join_query()
-    r1 = run_adaptive(db, q, syntactic_plan(q), est)
-    r2 = run_adaptive(db, q, syntactic_plan(q), est)       # warm: cache hit
+    cache = StageCache()
+    r1 = run_adaptive(db, q, syntactic_plan(q), est, cache=cache)
+    r2 = run_adaptive(db, q, syntactic_plan(q), est,
+                      cache=cache)                         # warm: cache hit
     assert [s.out_rows for s in r2.stages] == [s.out_rows for s in r1.stages]
-    hits_before = db._stage_cache.stats.hits
+    hits_before = cache.stats.hits
     assert hits_before > 0
 
-    counts = apply_delta(db, DeltaBatch("movie_info", n_append=2000, seed=1))
+    counts = apply_delta(db, DeltaBatch("movie_info", n_append=2000, seed=1),
+                         cache)
     assert counts["appended"] == 2000
     assert db.table_version("movie_info") == 1
-    assert db._stage_cache.stats.invalidations == 1
+    assert cache.stats.invalidations == 1
 
-    r3 = run_adaptive(db, q, syntactic_plan(q), est)       # post-delta
-    ref = run_adaptive(db, q, syntactic_plan(q), est, reuse_stages=False)
+    r3 = run_adaptive(db, q, syntactic_plan(q), est,
+                      cache=cache)                         # post-delta
+    ref = run_adaptive(db, q, syntactic_plan(q), est)      # no cache
     # bit-for-bit vs cache-off on the NEW data — a stale cached stage
     # would differ, because appended rows join with existing titles
     assert r3.latency == ref.latency
@@ -226,6 +262,8 @@ def test_straggler_does_not_block_other_lanes(job_workload, agent):
     assert done_before_async == 6 and done_before_lock <= 1
     p99 = lambda comps: float(np.percentile([c.latency for c in comps], 99))
     assert p99(a) < p99(ls), "async must beat lockstep on a straggler mix"
+    # ...and on throughput: the same work drains sooner on the clock
+    assert max(c.finish_t for c in a) < max(c.finish_t for c in ls)
 
 
 def test_lockstep_policy_matches_rollout_batch(job_db, job_workload,
