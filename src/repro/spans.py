@@ -24,12 +24,17 @@ passed to `span(...)`; those known only at its end are added with
 - `rows`, `method`: the stage's output rows and join method, which say
   why one executor span is long;
 - `probe`: how a join that the stage cache did not serve matched its
-  keys ("unique", "dense" or "sorted", `sql.executor._join_indices`);
+  keys ("unique", "dense" or "sorted", `sql.executor._join_plan`);
   absent on a stage-cache hit;
 - `cols`, `dropped`: on a join that the stage cache did not serve, the
   key columns its output carries (those a later join of the query reads,
   `sql.executor._live_cols`; 0 at the query's last join) and the input
-  columns it left out; absent on a stage-cache hit.
+  columns it left out; absent on a stage-cache hit;
+- `built`: on a join that the stage cache did not serve, the row-index
+  arrays it built: 2 with residual conditions (`_join_indices`' pairs),
+  else 1 when its output reads a right-side column and 0 when not (left
+  columns are built from the probe's match counts); absent on a
+  stage-cache hit.
 
 The names below are part of the program's interface: a profile reader
 finds the layers by them. Every name starts with `lqrs.`, and spans of one
@@ -68,7 +73,8 @@ SPANS = (
     ("lqrs.exec.scan", "AdaptiveRun: one base-table scan, or a stage-cache "
      "hit, and its charge [rows, hit]"),
     ("lqrs.exec.join", "AdaptiveRun: one join stage, or a stage-cache "
-     "hit, and its charge [rows, hit, method, probe, cols, dropped]"),
+     "hit, and its charge [rows, hit, method, probe, cols, dropped, "
+     "built]"),
 )
 
 _NAMES = frozenset(name for name, _ in SPANS)

@@ -129,45 +129,91 @@ def _stable_order(key: np.ndarray, k: int) -> np.ndarray:
     return low[high]
 
 
-def _join_indices(lkey: np.ndarray, rkey: np.ndarray, cap: int):
-    """Exact inner-join row indices, left-major with each left row's
-    matches in increasing right index; counts matches first and raises on
-    blowup before the output is expanded. Returns (lidx, ridx, probe),
-    equal to `_join_indices_sorted`'s pair on every input.
+@dataclasses.dataclass
+class JoinMatches:
+    """An inner join's matches without its left row indices: the output
+    is left-major with each left row's matches in increasing right index
+    (`_join_indices_sorted`'s order), and each side's columns are built
+    from what the probe found."""
+    total: int
+    probe: str
+    lsel: np.ndarray             # a mask over the left rows (each matches
+    #   at most once) or each left row's match count
+    ridx: Optional[np.ndarray]   # each output row's right row, when asked
+
+    def left(self, col: np.ndarray) -> np.ndarray:
+        """`col[lidx]`, read in order with no index array."""
+        if self.lsel.dtype == bool:
+            return col[self.lsel]
+        return np.repeat(col, self.lsel)
+
+    def right(self, col: np.ndarray) -> np.ndarray:
+        return col.take(self.ridx)
+
+    @property
+    def built(self) -> int:
+        """Row-index arrays built: `ridx` or none."""
+        return int(self.ridx is not None)
+
+
+def _join_plan(lkey: np.ndarray, rkey: np.ndarray, cap: int,
+               need_right: bool) -> JoinMatches:
+    """An exact inner join, built only as far as the output reads it:
+    counts matches first and raises on blowup before anything is
+    expanded, and builds the right row indices only when `need_right`.
 
     Integer keys in a dense domain [0, K) are probed through tables
     indexed by key (`probe` "unique": a position table when the right
     keys are unique; "dense": match counts, and a radix order of the
     right rows that match, otherwise); any other keys take the
-    sort-and-search path ("sorted")."""
-    k = _dense_domain(lkey, rkey)
-    if not k:
-        return (*_join_indices_sorted(lkey, rkey, cap), "sorted")
-    rkey = rkey.astype(np.intp, copy=False)
-    counts = np.bincount(rkey, minlength=k)
-    if counts.max() <= 1:
-        pos = np.full(k, -1, np.intp)
-        pos[rkey] = np.arange(len(rkey))
-        r_all = pos[lkey]
-        lidx = np.flatnonzero(r_all >= 0)
-        if len(lidx) > cap:
-            raise QueryFailure("oom",
-                               f"join output {len(lidx)} rows exceeds cap")
-        return lidx, r_all[lidx], "unique"
-    cnt = counts[lkey]
-    total = int(cnt.sum())
+    sort-and-search path ("sorted"). An empty input, which has no dense
+    domain, matches nothing with no sort ("sorted")."""
+    if not (len(lkey) and len(rkey)):
+        probe, lsel = "sorted", np.zeros(len(lkey), bool)
+    elif not (k := _dense_domain(lkey, rkey)):
+        probe = "sorted"
+        order = np.argsort(rkey, kind="stable")
+        rs = rkey[order]
+        lo = np.searchsorted(rs, lkey, "left")
+        lsel = np.searchsorted(rs, lkey, "right") - lo
+    else:
+        rkey = rkey.astype(np.intp, copy=False)
+        counts = np.bincount(rkey, minlength=k)
+        if counts.max() <= 1:
+            probe = "unique"
+            pos = np.full(k, -1, np.intp)
+            pos[rkey] = np.arange(len(rkey))
+            r_all = pos[lkey]
+            lsel = r_all >= 0
+        else:
+            probe, lsel = "dense", counts[lkey]
+    total = int(np.count_nonzero(lsel) if lsel.dtype == bool
+                else lsel.sum())
     if total > cap:
         raise QueryFailure("oom", f"join output {total} rows exceeds cap")
-    # order only the right rows whose key the left holds: a selective
-    # join sorts a fraction of its right side
-    held = np.bincount(lkey, minlength=k) > 0
-    sel = np.flatnonzero(held[rkey])
-    order = sel[_stable_order(rkey[sel], k)]
-    counts[~held] = 0
-    lo = (np.cumsum(counts) - counts)[lkey]
-    lidx = np.repeat(np.arange(len(lkey)), cnt)
-    offs = np.repeat(lo - (np.cumsum(cnt) - cnt), cnt) + np.arange(total)
-    return lidx, order[offs], "dense"
+    if not need_right:
+        return JoinMatches(total, probe, lsel, None)
+    if not total:
+        return JoinMatches(0, probe, lsel, np.zeros(0, np.intp))
+    if probe == "unique":
+        return JoinMatches(total, probe, lsel, r_all[lsel])
+    if probe == "dense":
+        # order only the right rows whose key the left holds
+        held = np.bincount(lkey, minlength=k) > 0
+        sel = np.flatnonzero(held[rkey])
+        order = sel[_stable_order(rkey[sel], k)]
+        counts[~held] = 0
+        lo = (np.cumsum(counts) - counts)[lkey]
+    offs = np.repeat(lo - (np.cumsum(lsel) - lsel), lsel) + np.arange(total)
+    return JoinMatches(total, probe, lsel, order[offs])
+
+
+def _join_indices(lkey: np.ndarray, rkey: np.ndarray, cap: int):
+    """`_join_plan`'s join with both row indices built, for a join that
+    reads both sides at every matched pair. Returns (lidx, ridx, probe),
+    equal to `_join_indices_sorted`'s pair on every input."""
+    m = _join_plan(lkey, rkey, cap, True)
+    return m.left(np.arange(len(lkey))), m.ridx, m.probe
 
 
 def _live_cols(query: Query,
@@ -219,11 +265,13 @@ class Executor:
         self.db = db
         self.cluster = cluster if cluster is not None else ClusterModel()
         self._cache = cache
-        self.probe: Optional[str] = None   # last join's probe path, None
-        #   when the stage cache served it (see `_join_indices`)
-        self.cols: Optional[int] = None    # last join's output columns and
-        self.dropped: Optional[int] = None  # input columns it left out; None
-        #   when the stage cache served it
+        # the last join's probe path (see `_join_plan`), output columns,
+        # input columns it left out and row-index arrays it built; None
+        # when the stage cache served it
+        self.probe: Optional[str] = None
+        self.cols: Optional[int] = None
+        self.dropped: Optional[int] = None
+        self.built: Optional[int] = None
 
     @property
     def cache_stats(self):
@@ -275,11 +323,13 @@ class Executor:
         else:
             key_l, key_r = (c0.right, c0.rcol), (c0.left, c0.lcol)
 
-        self.probe = self.cols = self.dropped = None
+        self.probe = self.cols = self.dropped = self.built = None
         aliases = left.aliases | right.aliases
         # only the keys a later join reads are gathered: the probe key and
         # the residual conditions are read from the inputs, and the last
-        # stage of a query carries its row count alone
+        # stage of a query carries its row count alone. Without residual
+        # conditions no left row index is built (`_join_plan`), and no
+        # right one unless a right column is live
         live = _live_cols(query, aliases)
         sig = None
         if self._cache is not None and left.sig is not None \
@@ -298,28 +348,37 @@ class Executor:
         else:
             lkey = left.columns[key_l]
             rkey = right.columns[key_r]
-            lidx, ridx, self.probe = _join_indices(lkey, rkey,
-                                                   cl.materialize_cap)
-            pre_total = len(lidx)
-            # residual equality conditions
-            keep = np.ones(len(lidx), bool)
-            for c in conds[1:]:
-                if c.left in left.aliases:
-                    la, ra = (c.left, c.lcol), (c.right, c.rcol)
-                else:
-                    la, ra = (c.right, c.rcol), (c.left, c.lcol)
-                keep &= left.columns[la][lidx] == right.columns[ra][ridx]
-            if not keep.all():
-                lidx, ridx = lidx[keep], ridx[keep]
-            out_cols = {k: (left.columns[k][lidx] if k[0] in left.aliases
-                            else right.columns[k][ridx]) for k in live}
+            if conds[1:]:
+                # residual equality conditions read both sides' rows
+                lidx, ridx, self.probe = _join_indices(lkey, rkey,
+                                                       cl.materialize_cap)
+                pre_total = len(lidx)
+                keep = np.ones(len(lidx), bool)
+                for c in conds[1:]:
+                    if c.left in left.aliases:
+                        la, ra = (c.left, c.lcol), (c.right, c.rcol)
+                    else:
+                        la, ra = (c.right, c.rcol), (c.left, c.lcol)
+                    keep &= left.columns[la][lidx] == right.columns[ra][ridx]
+                if not keep.all():
+                    lidx, ridx = lidx[keep], ridx[keep]
+                nrows, self.built = len(lidx), 2
+                out_cols = {k: (left.columns[k][lidx] if k[0] in left.aliases
+                                else right.columns[k][ridx]) for k in live}
+            else:
+                m = _join_plan(lkey, rkey, cl.materialize_cap,
+                               any(a in right.aliases for a, _ in live))
+                pre_total = nrows = m.total
+                self.probe, self.built = m.probe, m.built
+                out_cols = {k: (m.left(left.columns[k]) if k[0] in left.aliases
+                                else m.right(right.columns[k])) for k in live}
             self.cols = len(out_cols)
             self.dropped = len(left.columns) + len(right.columns) - self.cols
-            out = MaterializedRel(aliases, out_cols, len(lidx),
+            out = MaterializedRel(aliases, out_cols, nrows,
                                   left.width + right.width, sig=sig)
             if sig is not None:
                 nbytes = sum(v.nbytes for v in out_cols.values())
-                self._cache.put(sig, (dict(out_cols), len(lidx), pre_total),
+                self._cache.put(sig, (dict(out_cols), nrows, pre_total),
                                 nbytes)
 
         # ---- latency + shuffle accounting
@@ -639,7 +698,7 @@ class AdaptiveRun:
                     sp.set_metadata(rows=int(out.nrows), hit=int(hit))
                     if ex.probe is not None:
                         sp.set_metadata(probe=ex.probe, cols=ex.cols,
-                                        dropped=ex.dropped)
+                                        dropped=ex.dropped, built=ex.built)
                     charge(rec.seconds)
                 if trace is not None:
                     trace.stage(out.aliases, method, e0, state.elapsed,

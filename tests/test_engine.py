@@ -5,6 +5,7 @@ operator switching, OOM/timeout semantics, shuffle accounting.
 Property-style tests use seeded sweeps (hypothesis is not installed in this
 offline container — see DESIGN.md §Testing note)."""
 import itertools
+import types
 
 import numpy as np
 import pytest
@@ -192,7 +193,7 @@ def _zipf_keys(rng, n, domain, a=1.2):
 
 
 def _probe_case(name):
-    """(lkey, rkey, the probe path `_join_indices` must take)."""
+    """(lkey, rkey, the probe path `_join_plan` must take)."""
     rng = np.random.default_rng(PROBE_CASES.index(name))
     if name == "unique":            # key-to-id join
         return (_zipf_keys(rng, 5000, 900), rng.permutation(800), "unique")
@@ -259,6 +260,58 @@ def test_join_probe_raises_oom_where_the_reference_does(case):
         assert f"join output {total} rows" in str(err.value)
 
 
+@pytest.mark.parametrize("need_right", (False, True))
+@pytest.mark.parametrize("case", PROBE_CASES)
+def test_join_plan_builds_the_reference_columns(case, need_right):
+    """The columns `_join_plan` builds equal those gathered through the
+    reference's row-index pairs, dtype and all, with the same total and
+    the same blowup one row under it; right row indices are built only
+    for an output that reads the right side."""
+    lkey, rkey, path = _probe_case(case)
+    want_l, want_r = executor._join_indices_sorted(lkey, rkey, 10**9)
+    total = len(want_l)
+    m = executor._join_plan(lkey, rkey, total, need_right)
+    assert (m.total, m.probe, m.built) == (total, path, int(need_right))
+    built = [(m.left(c), c[want_l]) for c in
+             (lkey, np.arange(len(lkey), dtype=np.int32) * 7)]
+    if need_right:
+        built += [(m.right(c), c[want_r]) for c in
+                  (rkey, np.linspace(0.0, 1.0, len(rkey)))]
+    for got, want in built:
+        assert np.array_equal(got, want) and got.dtype == want.dtype
+    with pytest.raises(QueryFailure) as err:
+        executor._join_plan(lkey, rkey, total - 1, need_right)
+    assert err.value.kind == "oom"
+    assert f"join output {total} rows" in str(err.value)
+
+
+@pytest.mark.parametrize("case", ("empty_left", "empty_right"))
+def test_join_plan_sorts_nothing_for_an_empty_input(case, monkeypatch):
+    """An empty input matches nothing at once: the other side is neither
+    sorted nor searched."""
+    lkey, rkey, _ = _probe_case(case)
+
+    def refuse(*args, **kwargs):
+        raise AssertionError("an empty join sorted its other side")
+
+    monkeypatch.setattr(np, "argsort", refuse)
+    monkeypatch.setattr(np, "searchsorted", refuse)
+    m = executor._join_plan(lkey, rkey, 10**9, True)
+    assert (m.total, m.probe, m.built) == (0, "sorted", 1)
+    assert len(m.left(lkey)) == len(m.right(rkey)) == 0
+    assert m.left(lkey).dtype == lkey.dtype
+
+
+def _sorted_pairs_plan(lkey, rkey, cap, need_right):
+    """A `_join_plan` that gathers both sides through the sort-and-search
+    reference's full row-index pairs, as every join stage did before it
+    read match counts."""
+    lidx, ridx = executor._join_indices_sorted(lkey, rkey, cap)
+    return types.SimpleNamespace(
+        total=len(lidx), probe="pairs", built=2, ridx=ridx,
+        left=lambda col: col[lidx], right=lambda col: col[ridx])
+
+
 def test_join_probe_changes_no_run(job_db, estimator, job_workload,
                                    monkeypatch):
     """JOB-like queries run through the executor give the same stages,
@@ -271,20 +324,17 @@ def test_join_probe_changes_no_run(job_db, estimator, job_workload,
                 for q in queries]
 
     seen = []
-    probe = executor._join_indices
+    plan = executor._join_plan
 
-    def spy(lkey, rkey, cap):
-        out = probe(lkey, rkey, cap)
-        seen.append(out[2])
+    def spy(*args):
+        out = plan(*args)
+        seen.append(out.probe)
         return out
 
-    monkeypatch.setattr(executor, "_join_indices", spy)
+    monkeypatch.setattr(executor, "_join_plan", spy)
     fast = runs()
     assert {"unique", "dense"} <= set(seen)
-    monkeypatch.setattr(
-        executor, "_join_indices",
-        lambda l, r, cap: (*executor._join_indices_sorted(l, r, cap),
-                           "sorted"))
+    monkeypatch.setattr(executor, "_join_plan", _sorted_pairs_plan)
     for a, b in zip(fast, runs()):
         assert a.stages == b.stages
         assert a.latency == b.latency and a.failed == b.failed
@@ -303,6 +353,42 @@ def test_join_probe_is_named_on_cache_misses_only():
     assert ex.probe == "unique"
     again, _ = ex.join(q, b, a, q.conds[:1], SMJ)
     assert ex.probe is None and again.nrows == first.nrows
+
+
+def test_residual_conditions_join_through_row_index_pairs(monkeypatch):
+    """A join with a residual condition reads both sides' rows at every
+    matched pair: it builds both row indices and keeps the pairs that
+    meet the condition, and the query still counts the rows a brute
+    force gives."""
+    db = _tiny_db(4)
+    q = Query("two", (Relation("a", "a"), Relation("b", "b"),
+                      Relation("c", "c")),
+              (JoinCond("a", "id", "b", "a_id"),
+               JoinCond("a", "x", "b", "c_id"),
+               JoinCond("b", "c_id", "c", "id")))
+    ex = Executor(db)
+    a, _ = ex.scan(q, "a")
+    b, _ = ex.scan(q, "b")
+    out, _ = ex.join(q, a, b, q.conds[:2], SMJ)
+    assert (ex.probe, ex.built) == ("dense", 2)
+    lidx, ridx = executor._join_indices_sorted(
+        a.columns[("a", "id")], b.columns[("b", "a_id")], 10**9)
+    keep = a.columns[("a", "x")][lidx] == b.columns[("b", "c_id")][ridx]
+    assert 0 < out.nrows == int(keep.sum()) < len(lidx)
+    assert np.array_equal(out.columns[("b", "c_id")],
+                          b.columns[("b", "c_id")][ridx[keep]])
+    built, join = [], Executor.join
+
+    def spy(self, *args):
+        out = join(self, *args)
+        built.append(self.built)
+        return out
+
+    monkeypatch.setattr(Executor, "join", spy)
+    res = run_adaptive(db, q, syntactic_plan(q), Estimator(db, db.stats),
+                       ClusterModel())
+    assert res.stages[-1].out_rows == _brute_force_count(db, q)
+    assert built == [2, 0]      # then the last join, a count
 
 
 def _full_gather_join(self, query, left, right, conds, method):
@@ -438,6 +524,51 @@ def test_live_column_joins_change_no_run(pool, plans, monkeypatch):
         assert a.latency == b.latency
         assert (a.failed, a.failure_kind) == (b.failed, b.failure_kind)
         assert a.final_plan == b.final_plan
+
+
+def test_match_count_joins_change_no_run(pool, monkeypatch):
+    """A serving pool, on plans a hook rewrites at each boundary and with
+    a stage cache, builds the same intermediates (columns, dtypes, rows)
+    and gives the same stages, latency and final plans with joins built
+    from match counts as with the sort-and-search reference's pairs
+    forced for every stage. Joins built from match counts build no left row index, and a
+    right one only for an output that reads the right side."""
+    db, est, queries = pool
+    join = Executor.join
+
+    def runs():
+        outs = []
+
+        def spy(self, query, left, right, conds, method):
+            out, rec = join(self, query, left, right, conds, method)
+            reads_right = any(a in right.aliases for a, _ in out.columns)
+            outs.append((out.nrows, out.columns, self.probe, self.built,
+                         2 if len(conds) > 1 else int(reads_right)))
+            return out, rec
+
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(Executor, "join", spy)
+            results = _serve_pool(db, est, queries, _rewriting_hook(queries))
+        return results, outs
+
+    fast, fast_outs = runs()
+    monkeypatch.setattr(executor, "_join_plan", _sorted_pairs_plan)
+    pairs, pairs_outs = runs()
+    for a, b in zip(fast, pairs):
+        assert a.stages == b.stages
+        assert a.latency == b.latency
+        assert (a.failed, a.failure_kind) == (b.failed, b.failure_kind)
+        assert a.final_plan == b.final_plan
+    assert len(fast_outs) == len(pairs_outs)
+    for (n, cols, probe, built, want), (pn, pcols, pprobe, *_) in zip(
+            fast_outs, pairs_outs):
+        assert n == pn and cols.keys() == pcols.keys()
+        for k in cols:
+            assert np.array_equal(cols[k], pcols[k])
+            assert cols[k].dtype == pcols[k].dtype
+        assert (probe is None) == (pprobe is None)
+        assert built == (None if probe is None else want)
+    assert {o[3] for o in fast_outs} >= {0, 1}
 
 
 def _read_later(query, aliases):

@@ -165,6 +165,12 @@ def test_exec_span_hits_are_the_stage_cache_hits(traced):
     assert not any("cols" in x or "dropped" in x for x in joins if x["hit"])
     assert any(x["cols"] == 0 for x in missed)       # a query's last join
     assert any(x["dropped"] > 0 for x in missed)
+    # and the row-index arrays it built: no right one for an output that
+    # reads no column, as at a query's last join
+    assert all(x["built"] in (0, 1, 2) for x in missed)
+    assert all(x["built"] != 1 for x in missed if x["cols"] == 0)
+    assert any(x["built"] == 0 for x in missed)
+    assert not any("built" in x for x in joins if x["hit"])
     assert len(joins) >= sum(len(c.result.stages) for c in comps)
 
 
